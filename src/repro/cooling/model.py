@@ -50,7 +50,8 @@ import jax.numpy as jnp
 from repro.core.types import CoolingState
 from repro.kernels.power_topo import ops as topo_ops
 from repro.kernels.power_topo.ref import (CduParams, cdu_update_ref,
-                                          hall_matrix, hall_max_ref)
+                                          hall_matrix, hall_max_ref,
+                                          segment_dot)
 from repro.systems.config import CoolingConfig
 
 
@@ -183,12 +184,12 @@ def _finish_step(cfg: CoolingConfig, state: CoolingState, dt: float,
     them (the hierarchical fused kernel) — recomputed here otherwise."""
     hs = halls(cfg)
     if q_hall is None:
-        q_hall = q @ hs.hmat
+        q_hall = segment_dot(q, hs.hmat)
 
     # water temperature arriving at each hall's towers = the hall's
     # flow-weighted return temp; the facility scalar mixes all groups
-    mdot_hall = mdot @ hs.hmat
-    t_ret_mix_hall = (mdot * t_return) @ hs.hmat / \
+    mdot_hall = segment_dot(mdot, hs.hmat)
+    t_ret_mix_hall = segment_dot(mdot * t_return, hs.hmat) / \
         jnp.maximum(mdot_hall, 1e-6)
     t_ret_mix = jnp.sum(mdot * t_return) / jnp.maximum(jnp.sum(mdot), 1e-6)
 

@@ -619,6 +619,18 @@ def _sweep_fn(system: SystemConfig, n_steps: int, w_axis, events=None):
     return fn
 
 
+def _stack_weather(weather, scens):
+    """(weather operand, vmap axis): a list of traces, one per scenario,
+    stacks onto the batch axis (axis 0); a single trace or None is shared
+    by broadcast (axis None)."""
+    if isinstance(weather, (list, tuple)):
+        if len(weather) != len(scens):
+            raise ValueError(f"need one weather trace per scenario: "
+                             f"{len(weather)} != {len(scens)}")
+        return wsig.stack_weather(weather), 0
+    return weather, None
+
+
 def simulate_sweep(system: SystemConfig, table: T.JobTable,
                    scens: list[T.Scenario], t0: float, t1: float,
                    accounts: T.AccountStats | None = None,
@@ -647,16 +659,49 @@ def simulate_sweep(system: SystemConfig, table: T.JobTable,
     n_steps = int(round((t1 - t0) / system.dt))
     st0 = init_state(system, table, t0, t1, accounts, num_accounts, events)
     batched = T.stack_scenarios(scens)
-    if isinstance(weather, (list, tuple)):
-        if len(weather) != len(scens):
-            raise ValueError(f"need one weather trace per scenario: "
-                             f"{len(weather)} != {len(scens)}")
-        weather_b, w_axis = wsig.stack_weather(weather), 0
-    else:
-        weather_b, w_axis = weather, None
-
+    weather_b, w_axis = _stack_weather(weather, scens)
     run = _sweep_fn(system, n_steps, w_axis, events)
     return run(table, st0, batched, signals, weather_b)
+
+
+def sharded_sweep_fn(system: SystemConfig, n_steps: int, w_axis, events,
+                     mesh):
+    """Cached jitted runner of ``simulate_sweep_sharded`` on ``mesh`` (a
+    1-D ``("scenario",)`` mesh, ``repro.parallel.sharding.sweep_mesh``).
+
+    Takes (table, st0, stacked scenarios, signals, weather); the scenario
+    batch (and a stacked weather batch, ``w_axis == 0``) must divide the
+    mesh size. Exposed so the program can be lowered for a mesh of
+    devices that are described rather than attached."""
+    from repro.parallel import sharding as psh
+
+    # compiled-program cache, same rationale as _sweep_fn: per-generation
+    # training rollouts re-enter here with identical shapes
+    key = ("sharded", system, n_steps, w_axis, mesh, events)
+    run = _cache_lookup(key)
+    if run is not None:
+        return run
+    scen_spec = psh.scenario_spec()
+    rep = jax.sharding.PartitionSpec()
+    w_spec = scen_spec if w_axis == 0 else rep
+
+    def shard(table_s, st0_s, scen_s, signals_s, weather_s):
+        def one(scen1, weather1):
+            def body(st, _):
+                return engine_step(system, table_s, st, scen1, signals_s,
+                                   weather1, events)
+            return jax.lax.scan(body, st0_s, None, length=n_steps)
+        return jax.vmap(one, in_axes=(0, w_axis))(scen_s, weather_s)
+
+    # check_vma=False: every loop carry inside engine_step (the scan's
+    # replicated initial state, the admission fori_loop's fresh constants)
+    # becomes per-device data after one step, which the varying-axes type
+    # check rejects. The body has no collective whose result depends on
+    # those types: scenario rows never communicate.
+    run = jax.jit(jax.shard_map(
+        shard, mesh=mesh, in_specs=(rep, rep, scen_spec, rep, w_spec),
+        out_specs=scen_spec, check_vma=False))
+    return _cache_store(key, run)
 
 
 def simulate_sweep_sharded(system: SystemConfig, table: T.JobTable,
@@ -669,66 +714,33 @@ def simulate_sweep_sharded(system: SystemConfig, table: T.JobTable,
                            ) -> Tuple[T.SimState, T.StepRecord]:
     """``simulate_sweep`` with the scenario axis sharded across devices.
 
-    One ``shard_map`` over a 1-D ``("scenario",)`` mesh
-    (repro.parallel.sharding.sweep_mesh): each device scans its slice of
+    One ``jax.shard_map`` over a 1-D ``("scenario",)`` mesh
+    (``repro.parallel.sharding.sweep_mesh()`` over every local device;
+    ``sharded_sweep_fn`` takes any other): each device scans its slice of
     the scenario batch with the job table, initial state and grid signals
     replicated — scenario rows never communicate, so the program contains
-    no collectives and scales linearly across hosts. Per-scenario weather
-    (a list, possibly hall-stacked — see ``cooling.weather.stack_halls``)
-    is sharded with the scenarios. The batch is padded to the device
-    count by replicating the last scenario; padded rows are sliced off
-    the result. With a single device this degenerates to exactly
-    ``simulate_sweep`` (one vmapped program, no sharding machinery).
+    no collectives and scales linearly across hosts. Per-scenario weather (a list, possibly
+    hall-stacked — see ``cooling.weather.stack_halls``) is sharded with
+    the scenarios. The batch is padded to the device count by replicating
+    the last scenario; padded rows are sliced off the result. With a
+    single device this degenerates to exactly ``simulate_sweep`` (one
+    vmapped program, no sharding machinery).
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.parallel import sharding as psh
 
-    n_dev = len(jax.devices())
+    mesh = psh.sweep_mesh()
+    n_dev = mesh.devices.size
     if n_dev <= 1:
         return simulate_sweep(system, table, scens, t0, t1, accounts,
                               num_accounts, signals, weather, events)
     n_steps = int(round((t1 - t0) / system.dt))
     st0 = init_state(system, table, t0, t1, accounts, num_accounts, events)
-    batched = T.stack_scenarios(scens)
-    if isinstance(weather, (list, tuple)):
-        if len(weather) != len(scens):
-            raise ValueError(f"need one weather trace per scenario: "
-                             f"{len(weather)} != {len(scens)}")
-        weather_b, w_axis = wsig.stack_weather(weather), 0
-    else:
-        weather_b, w_axis = weather, None
-
+    weather_b, w_axis = _stack_weather(weather, scens)
     S = len(scens)
-    batched, _ = psh.pad_leading_axis(batched, n_dev)
+    batched, _ = psh.pad_leading_axis(T.stack_scenarios(scens), n_dev)
     if w_axis == 0:
         weather_b, _ = psh.pad_leading_axis(weather_b, n_dev)
-
-    # compiled-program cache, same rationale as _sweep_fn: per-generation
-    # training rollouts re-enter here with identical shapes
-    key = ("sharded", system, n_steps, w_axis, n_dev, events)
-    run = _cache_lookup(key)
-    if run is None:
-        mesh = psh.sweep_mesh()
-        scen_spec = psh.scenario_spec()
-        w_spec = scen_spec if w_axis == 0 else jax.sharding.PartitionSpec()
-        rep = jax.sharding.PartitionSpec()
-
-        @jax.jit
-        def run(table_, st0_, scen_, signals_, weather_):
-            def shard(table_s, st0_s, scen_s, signals_s, weather_s):
-                def one(scen1, weather1):
-                    def body(st, _):
-                        return engine_step(system, table_s, st, scen1,
-                                           signals_s, weather1, events)
-                    return jax.lax.scan(body, st0_s, None, length=n_steps)
-                return jax.vmap(one, in_axes=(0, w_axis))(scen_s, weather_s)
-            return shard_map(shard, mesh=mesh,
-                             in_specs=(rep, rep, scen_spec, rep, w_spec),
-                             out_specs=scen_spec)(
-                table_, st0_, scen_, signals_, weather_)
-        _cache_store(key, run)
-
+    run = sharded_sweep_fn(system, n_steps, w_axis, events, mesh)
     final, hist = run(table, st0, batched, signals, weather_b)
     trim = lambda x: x[:S]
     return (jax.tree_util.tree_map(trim, final),

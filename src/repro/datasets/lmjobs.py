@@ -1,18 +1,11 @@
-"""AI-workload dataset: the twin schedules LM training/serving jobs whose
-power behavior comes from the *compiled* workload layer.
+"""AI-workload dataset: the twin schedules LM training/serving jobs.
 
-Each job is a (arch x shape) run from the assigned grid; its per-node power
-is derived from the cell's roofline terms (results/dryrun/*__final.json):
-compute-bound cells run nodes near peak power, collective/memory-bound cells
-idle the compute units proportionally to the dominant-term ratio —
-the standard utilization->power proxy, fed by real compiled artifacts.
-Falls back to an analytic table when no dry-run artifacts exist.
+Each job is a (arch x shape) run from the assigned grid. Its per-node
+power comes from an analytic utilisation table — every runnable cell at
+the same utilisation (``_CELL_UTIL``), jittered per job — through the
+standard utilization->power proxy ``idle + (peak - idle) * util``.
 """
 from __future__ import annotations
-
-import glob
-import json
-import pathlib
 
 import numpy as np
 
@@ -20,25 +13,15 @@ from repro.datasets.base import JobSet
 from repro.datasets.synthetic import event_schedule
 from repro.systems.config import SystemConfig
 
-DRYRUN = pathlib.Path(__file__).resolve().parents[3] / "results" / "dryrun"
-
-# fallback utilization if no dry-run artifacts are present
-_FALLBACK_UTIL = 0.6
+# compute-unit utilisation of every (arch x shape) cell
+_CELL_UTIL = 0.6
 
 
 def _cell_utilization() -> dict:
-    """(arch, shape) -> compute-term / dominant-term from the dry-run."""
-    out = {}
-    for f in glob.glob(str(DRYRUN / "*__extrap__final.json")):
-        rec = json.load(open(f))
-        if rec.get("status") != "OK":
-            continue
-        rf = rec["roofline"]
-        dom = max(rf["t_compute_s"], rf["t_memory_s"], rf["t_collective_s"])
-        parts = rec["cell"].split("__")
-        if dom > 0:
-            out[(parts[0], parts[1])] = min(rf["t_compute_s"] / dom, 1.0)
-    return out
+    """(arch, shape) -> utilisation for every runnable cell of the grid."""
+    from repro.configs import ARCHS, SHAPES
+    return {(a, s): _CELL_UTIL for a in ARCHS for s in SHAPES
+            if s not in ARCHS[a].skip_shapes}
 
 
 def generate_lm_workload(system: SystemConfig, n_jobs: int = 256,
@@ -47,16 +30,12 @@ def generate_lm_workload(system: SystemConfig, n_jobs: int = 256,
     """Jobs = LM runs drawn from the assigned (arch x shape) grid.
 
     Returns a ``JobSet`` with times in s and scalar per-node power
-    profiles (W) derived from each cell's roofline utilization
+    profiles (W) derived from each cell's utilisation
     (idle + (peak - idle) * util); walltimes are grid-aligned to
     ``system.dt`` and a ground-truth schedule is recorded via
     ``event_schedule`` (replay semantics, paper §3.2.2)."""
     rng = np.random.default_rng(seed)
     cells = _cell_utilization()
-    if not cells:
-        from repro.configs import ARCHS, SHAPES
-        cells = {(a, s): _FALLBACK_UTIL for a in ARCHS for s in SHAPES
-                 if s not in ARCHS[a].skip_shapes}
     keys = list(cells.keys())
     pick = rng.integers(0, len(keys), n_jobs)
 

@@ -13,16 +13,17 @@ import pathlib
 
 from repro.datasets.base import JobSet
 from repro.datasets.synthetic import WorkloadSpec, generate
-from repro.systems.config import get_system
+from repro.systems.config import SystemConfig, get_system
 
 DAY = 86400.0
 
 
 def load_frontier(n_jobs: int = 1238, days: float = 1.0, seed: int = 1,
-                  full_system_jobs: int = 3) -> JobSet:
+                  full_system_jobs: int = 3,
+                  system: SystemConfig | None = None) -> JobSet:
     """Frontier excerpt: 15 s traces, priority FIFO boosted by node count,
     includes the Fig. 6 pattern of full-system (9,600-node) runs."""
-    sys = get_system("frontier")
+    sys = system or get_system("frontier")
     spec = WorkloadSpec(n_jobs=n_jobs, duration_s=days * DAY, load=0.92,
                         n_accounts=48, mean_wall_s=5400.0,
                         max_frac_nodes=0.30,
@@ -32,10 +33,11 @@ def load_frontier(n_jobs: int = 1238, days: float = 1.0, seed: int = 1,
 
 
 def load_marconi100(n_jobs: int = 2000, days: float = 1.0,
-                    seed: int = 2) -> JobSet:
+                    seed: int = 2,
+                    system: SystemConfig | None = None) -> JobSet:
     """PM100: 20 s traces; shared-node jobs are filtered upstream (paper),
     so utilization does not reflect full production load; queues fill."""
-    sys = get_system("marconi100")
+    sys = system or get_system("marconi100")
     spec = WorkloadSpec(n_jobs=n_jobs, duration_s=days * DAY, load=1.15,
                         n_accounts=32, mean_wall_s=2700.0,
                         max_frac_nodes=0.20, trace_len=64, seed=seed)
@@ -43,28 +45,31 @@ def load_marconi100(n_jobs: int = 2000, days: float = 1.0,
 
 
 def load_fugaku(n_jobs: int = 4000, days: float = 1.0, seed: int = 3,
-                load: float = 0.75) -> JobSet:
+                load: float = 0.75,
+                system: SystemConfig | None = None) -> JobSet:
     """F-Data: job summaries, node-level power only (scalar profiles)."""
-    sys = get_system("fugaku")
+    sys = system or get_system("fugaku")
     spec = WorkloadSpec(n_jobs=n_jobs, duration_s=days * DAY, load=load,
                         n_accounts=64, mean_wall_s=4500.0,
                         max_frac_nodes=0.10, trace_len=1, seed=seed)
     return generate(sys, spec)
 
 
-def load_lassen(n_jobs: int = 3000, days: float = 1.0, seed: int = 4) -> JobSet:
+def load_lassen(n_jobs: int = 3000, days: float = 1.0, seed: int = 4,
+                system: SystemConfig | None = None) -> JobSet:
     """LAST: job summaries with accumulated energy (scalar profiles)."""
-    sys = get_system("lassen")
+    sys = system or get_system("lassen")
     spec = WorkloadSpec(n_jobs=n_jobs, duration_s=days * DAY, load=0.8,
                         n_accounts=40, mean_wall_s=7200.0,
                         max_frac_nodes=0.25, trace_len=1, seed=seed)
     return generate(sys, spec)
 
 
-def load_adastra(n_jobs: int = 1000, days: float = 15.0, seed: int = 5) -> JobSet:
+def load_adastra(n_jobs: int = 1000, days: float = 15.0, seed: int = 5,
+                 system: SystemConfig | None = None) -> JobSet:
     """Cirou's 15-day Adastra set: scalar component power, *low* system load
     (paper Fig. 5: queues do not fill; policy choice makes little difference)."""
-    sys = get_system("adastraMI250")
+    sys = system or get_system("adastraMI250")
     spec = WorkloadSpec(n_jobs=n_jobs, duration_s=days * DAY, load=0.55,
                         n_accounts=24, mean_wall_s=10800.0,
                         max_frac_nodes=0.35, trace_len=1, seed=seed)
@@ -84,7 +89,10 @@ LOADERS = {
 
 def load(system_name: str, **kw) -> JobSet:
     """Dispatch to the per-system loader (CLI ``--system``); ``kw`` is
-    forwarded (commonly ``n_jobs``, ``days``, ``seed``)."""
+    forwarded (commonly ``n_jobs``, ``days``, ``seed``). Every loader
+    also takes ``system``: the machine to size the jobs and the recorded
+    schedule for (default: the published system; pass a ``.scaled``
+    variant so a replay stays feasible on it)."""
     return LOADERS[system_name](**kw)
 
 

@@ -1,24 +1,22 @@
 """Jit'd wrappers for the power-topology kernels.
 
 ``group_power`` (segment reduce) and ``fused_cooling`` (segment reduce +
-CDU loop update in one pass) are what the engine calls. On CPU (this
-container) they lower to the XLA path (the oracle math); on TPU
-deployments set ``use_pallas=True`` to take the VMEM-tiled kernels. The
-wrappers own padding so the kernels only see aligned shapes.
+CDU loop update in one pass) default to the XLA path (the oracle math).
+``use_pallas=True`` takes the VMEM-tiled TPU kernels, compiled unless
+``interpret=True`` asks for the Pallas interpreter (the only way to run
+them off the TPU). The wrappers own padding so the kernels only see
+aligned shapes.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.kernels.power_topo.power_topo import (fused_cooling_pallas,
+from repro.kernels.power_topo.power_topo import (LANE, fused_cooling_pallas,
                                                  group_power_pallas)
 from repro.kernels.power_topo.ref import (CduParams, cdu_update_ref,
                                           fused_cooling_hier_ref,
                                           fused_cooling_ref, group_power_ref,
                                           hall_power_ref)
-
-_LANE = 128
-
 
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int) -> jnp.ndarray:
     size = x.shape[axis]
@@ -42,12 +40,12 @@ def _group_layout(x: jnp.ndarray, n_groups: int) -> jnp.ndarray:
     span = -(-N // n_groups)          # ceil: matches ref.group_ids
     x = _pad_to(x, 1, span * n_groups)
     x = x.reshape(S, n_groups, span)
-    x = _pad_to(x, 2, _LANE)
+    x = _pad_to(x, 2, LANE)
     return x.reshape(S, -1)
 
 
 def group_power(node_pw: jnp.ndarray, n_groups: int,
-                use_pallas: bool = False, interpret: bool = True
+                use_pallas: bool = False, interpret: bool = False
                 ) -> jnp.ndarray:
     """f32[N] or f32[S, N] -> f32[G] / f32[S, G]."""
     squeeze = node_pw.ndim == 1
@@ -67,7 +65,7 @@ def group_power(node_pw: jnp.ndarray, n_groups: int,
 def fused_cooling(node_pw: jnp.ndarray, t_supply: jnp.ndarray,
                   mdot: jnp.ndarray, t_basin: jnp.ndarray,
                   t_set: jnp.ndarray, n_groups: int, params: CduParams,
-                  use_pallas: bool = False, interpret: bool = True):
+                  use_pallas: bool = False, interpret: bool = False):
     """Fused per-step cooling update: per-CDU heat + loop state in one pass.
 
     Args:
@@ -124,7 +122,7 @@ def fused_cooling_hier(node_pw: jnp.ndarray, t_supply: jnp.ndarray,
                        mdot: jnp.ndarray, t_basin_hall: jnp.ndarray,
                        t_set, hall_of_group, n_groups: int,
                        params: CduParams, use_pallas: bool = False,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """Hierarchical fused cooling update: node -> CDU -> hall reduction +
     per-CDU loop update against each group's hall basin.
 

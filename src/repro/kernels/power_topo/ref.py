@@ -19,6 +19,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 
@@ -48,20 +49,28 @@ def group_ids(n_nodes: int, n_groups: int) -> np.ndarray:
     return np.minimum(idx // span, n_groups - 1)
 
 
+def segment_dot(x: jnp.ndarray, one_hot: jnp.ndarray) -> jnp.ndarray:
+    """``x @ one_hot`` at full f32 precision: the segment sum behind every
+    one-hot reduction here. The TPU's default f32 matmul rounds its
+    operands to bf16 (about three significant digits), which would round
+    each node's power before it is summed."""
+    return jnp.matmul(x, one_hot, precision=jax.lax.Precision.HIGHEST)
+
+
 def group_power_ref(node_pw: jnp.ndarray, n_groups: int) -> jnp.ndarray:
     """f32[..., N] -> f32[..., G] segment sum over contiguous node spans."""
     n_nodes = node_pw.shape[-1]
     gid = group_ids(n_nodes, n_groups)
     one_hot = (gid[:, None] == jnp.arange(n_groups)[None, :]).astype(
         node_pw.dtype)
-    return node_pw @ one_hot
+    return segment_dot(node_pw, one_hot)
 
 
 def hall_matrix(hall_of_group, n_halls: int,
                 dtype=jnp.float32) -> jnp.ndarray:
     """One-hot group->hall matrix f32[G, H] for the second reduction level
     of the node -> CDU -> hall hierarchy. ``x @ hall_matrix(...)`` is the
-    per-hall segment sum of a per-group quantity."""
+    per-hall segment sum of a per-group quantity (via ``segment_dot``)."""
     hog = jnp.asarray(hall_of_group, jnp.int32)
     return (hog[:, None] == jnp.arange(n_halls)[None, :]).astype(dtype)
 
@@ -69,7 +78,8 @@ def hall_matrix(hall_of_group, n_halls: int,
 def hall_power_ref(group_q: jnp.ndarray, hall_of_group,
                    n_halls: int) -> jnp.ndarray:
     """f32[..., G] -> f32[..., H] segment sum of per-group heat per hall."""
-    return group_q @ hall_matrix(hall_of_group, n_halls, group_q.dtype)
+    return segment_dot(group_q,
+                       hall_matrix(hall_of_group, n_halls, group_q.dtype))
 
 
 def hall_max_ref(group_x: jnp.ndarray, hall_of_group,

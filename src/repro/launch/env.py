@@ -16,10 +16,20 @@ retrofit them — it is a report-and-hint layer:
   ``/proc/self/maps`` for the actually-loaded allocator) and returns a
   JSON-able dict the run manifests embed, so a benchmark entry can be
   audited for its allocator/flag state after the fact.
+
+``enable_compile_cache()`` is the one place that turns on JAX's
+persistent compilation cache; entry points call it before their first
+compile.
 """
 from __future__ import annotations
 
 import os
+import pathlib
+
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+# not set: a fixed directory inside the checkout (gitignored). The path
+# is part of what a later run must find again, so it never moves.
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 # Candidate tcmalloc locations (Debian/Ubuntu multiarch, RHEL).
 _TCMALLOC_PATHS = (
@@ -100,3 +110,18 @@ def report(name: str = "throughput") -> dict:
         "satisfied": (allocator == "tcmalloc"
                       and want_flags <= have_flags),
     }
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set, JAX already reads it and
+    nothing is changed. Otherwise the cache goes to ``COMPILE_CACHE_DIR``.
+    Call it from an entry point before the first compile, never while a
+    module is being imported."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)

@@ -13,19 +13,18 @@ from __future__ import annotations
 
 import jax
 
-from repro.parallel.sharding import mesh_axis_types_kwargs
-
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Degenerate 1-device mesh for CPU tests (same axis names)."""
     return jax.make_mesh((1, 1), ("data", "model"),
-                         **mesh_axis_types_kwargs(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 # Hardware constants for the roofline (TPU v5e-class, per chip).

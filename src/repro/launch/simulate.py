@@ -84,14 +84,16 @@ def build_system(name: str, scale: int = 0, halls: int = 0):
     return sys_
 
 
-def main(argv=None):
+def main(argv=None) -> int:
+    """Run the CLI; returns the process exit code."""
     import sys as _sys
     argv = list(_sys.argv[1:]) if argv is None else list(argv)
     if argv[:1] == ["train"]:
         # policy-training subcommand (repro.ml.train): ES over batched
         # twin rollouts; everything after "train" is its own arg set
         from repro.ml import train as ml_train
-        return ml_train.main(argv[1:])
+        ml_train.main(argv[1:])
+        return 0
     if argv[:1] == ["serve"]:
         # twin-as-a-service (repro.serve, docs/serving.md): persistent
         # session with snapshot/fork branching over a socket
@@ -242,8 +244,10 @@ def main(argv=None):
         js = loaders.load_trace(args.trace, prof_dt=sys_.prof_dt,
                                 cache_dir=args.trace_cache)
     else:
+        # sized for the machine actually simulated (--scale), so a
+        # replayed schedule is feasible on it
         js = loaders.load(args.system, n_jobs=args.jobs, days=days,
-                          seed=args.seed)
+                          seed=args.seed, system=sys_)
     weather = None
     if args.weather_trace:
         from repro.traces.weather import load_weather
@@ -370,6 +374,7 @@ def main(argv=None):
         rep.info(f"manifest -> {args.manifest}" if args.manifest
                  else f"events -> {args.events}")
     rep.flush_json()
+    return 0
 
 
 def _trace_digests(args) -> dict:
@@ -540,4 +545,7 @@ def _run(args, sys_, js, table, accounts, t0, t1, cells_offline, recorder,
 
 
 if __name__ == "__main__":
-    main()
+    # the process entry point, not main(): in-process callers keep their
+    # own cache settings
+    launch_env.enable_compile_cache()
+    raise SystemExit(main())

@@ -31,27 +31,16 @@ from repro.models.common import ArchConfig, Annotated
 Rules = Dict[str, Any]
 
 
-def mesh_axis_types_kwargs(n_axes: int) -> Dict[str, Any]:
-    """kwargs for ``jax.make_mesh`` requesting Auto axis types, across JAX
-    versions: ``jax.sharding.AxisType`` (and the ``axis_types`` parameter)
-    only exist on newer JAX; older releases (e.g. 0.4.x) are Auto-only, so
-    omitting the kwarg is equivalent there."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
 # -- scenario-axis sharding (digital-twin sweeps) -----------------------------
-def sweep_mesh(n_devices: Optional[int] = None) -> Mesh:
-    """1-D ``("scenario",)`` mesh over the local devices: the what-if sweep
-    axis of ``engine.simulate_sweep_sharded``. Scenario rows are
-    embarrassingly parallel (they share the job table and signal arrays by
-    replication), so a flat mesh is always the right shape."""
-    devs = jax.devices()
-    if n_devices is not None:
-        devs = devs[:n_devices]
+def sweep_mesh(devices=None) -> Mesh:
+    """1-D ``("scenario",)`` mesh over ``devices`` (default: every local
+    device): the what-if sweep axis of ``engine.simulate_sweep_sharded``.
+    Scenario rows are embarrassingly parallel (they share the job table
+    and signal arrays by replication), so a flat mesh is always the right
+    shape."""
+    devs = jax.devices() if devices is None else list(devices)
     return Mesh(np.array(devs), ("scenario",),
-                **mesh_axis_types_kwargs(1))
+                axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def pad_leading_axis(tree, multiple: int):

@@ -25,7 +25,8 @@ from repro.serve.server import TwinServer
 from repro.serve.session import TwinSession
 
 
-def main(argv=None):
+def main(argv=None) -> int:
+    """Serve until shutdown; exits non-zero if an advance batch failed."""
     ap = argparse.ArgumentParser(
         prog="simulate serve", description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -83,7 +84,7 @@ def main(argv=None):
     t1 = t0 + steps * float(sys_.dt)
     days = args.days or max((t1 / 86400.0) * 1.25, 0.5)
     js = loaders.load(args.system, n_jobs=args.jobs, days=days,
-                      seed=args.seed)
+                      seed=args.seed, system=sys_)
     js.assign_prepop_placement(t0, sys_.n_nodes)
     table = js.to_table()
     scen = T.Scenario.make(args.policy, args.backfill)
@@ -106,8 +107,10 @@ def main(argv=None):
     stats = server.close()
     print(json.dumps({"served": stats["n_clients"],
                       "wire": stats["wire"],
-                      "session": stats["session"]}), flush=True)
-    return 0
+                      "session": stats["session"],
+                      "failed_batches": stats["failed_batches"]}),
+          flush=True)
+    return 1 if stats["failed_batches"] else 0
 
 
 if __name__ == "__main__":

@@ -91,6 +91,8 @@ class TwinServer:
         self._queue: List[_Pending] = []
         self._queue_cv = threading.Condition()
         self._shutdown = threading.Event()
+        # advance batches whose dispatch raised (each failed its clients)
+        self.failed_batches = 0
         self.recorder = None
         if obs_dir is not None:
             from repro.obs.recorder import RunRecorder
@@ -290,6 +292,7 @@ class TwinServer:
                 # later advance on done.wait and breaks the "server
                 # never dies on client behavior" guarantee
                 results, err = {}, SessionError(f"advance failed: {e!r}")
+                self.failed_batches += 1
                 self.session.count_error()
                 self._event("advance_batch_error", message=repr(e))
             self._event("advance_batch", branches=sorted(merged),
@@ -320,6 +323,7 @@ class TwinServer:
         return {"address": self.address, "wire": wire.as_dict(),
                 "session": dict(self.session.counters),
                 "clients": ledger,
+                "failed_batches": self.failed_batches,
                 "n_clients": len(ledger),
                 "n_open": sum(1 for c in ledger if c["open"])}
 

@@ -136,3 +136,30 @@ def test_calibrate_cli_check_gate(capsys):
                    "--check", str(CAL_DIR / "fitted_params.json")])
     assert rc == 0
     assert "envelope holds" in capsys.readouterr().out
+
+
+def test_calibrate_cli_check_exit_code_on_widened_envelope(tmp_path):
+    """``simulate calibrate --check`` exits with calibrate's own return
+    code: a committed envelope far tighter than the physics can meet
+    (the fresh envelope has widened past it) fails the process."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    blob = json.loads((CAL_DIR / "fitted_params.json").read_text())
+    blob["envelope"] = {ch: v * 1e-3 for ch, v in blob["envelope"].items()}
+    tight = tmp_path / "tight_params.json"
+    tight.write_text(json.dumps(blob))
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
+               PYTHONPATH=os.pathsep.join(
+                   [str(root / "src")] +
+                   [p for p in [os.environ.get("PYTHONPATH")] if p]))
+    out = subprocess.run(
+        [sys.executable, "-m", "repro.launch.simulate", "calibrate",
+         "--telemetry", str(CAL_DIR / "telemetry.npz"),
+         "--system", "frontier", "--check", str(tight)],
+        capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode == 1, out.stderr[-2000:]
+    assert "envelope widened" in out.stdout

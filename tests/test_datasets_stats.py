@@ -133,8 +133,9 @@ def test_stats_single_interval_run(small_system, small_table):
 
 
 def test_lm_workload_from_roofline_artifacts():
-    """The AI-workload dataset ties the twin to the compiled LM layer:
-    per-node power comes from each cell's roofline utilization."""
+    """The AI-workload dataset: per-node power comes from each (arch x
+    shape) cell's utilisation in the analytic table, within the
+    system's idle..peak envelope, and the twin schedules it."""
     from repro.core import engine as eng
     from repro.core import types as T
     from repro.datasets.lmjobs import generate_lm_workload

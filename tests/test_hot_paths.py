@@ -10,6 +10,7 @@ numerically, resume-from-segment stays bit-identical, the
 templates survive their carries being eaten.
 """
 import os
+import pathlib
 import subprocess
 import sys
 
@@ -194,3 +195,26 @@ def test_env_preset_report_and_manifest_embedding(tmp_path):
         assert os.environ["XLA_FLAGS"] == "--user-set"
     finally:
         del os.environ["XLA_FLAGS"]
+
+
+def test_compile_cache_helper_honours_env_else_fixed_checkout_path(
+        monkeypatch):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.launch import env as launch_env
+    root = pathlib.Path(__file__).resolve().parents[1]
+    assert launch_env.COMPILE_CACHE_DIR == root / ".jax_cache"
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert launch_env.enable_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        got = launch_env.enable_compile_cache()
+        assert got == str(launch_env.COMPILE_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == got
+        # idempotent: a second entry point in the same process agrees
+        assert launch_env.enable_compile_cache() == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        cc.reset_cache()
