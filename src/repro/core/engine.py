@@ -34,6 +34,7 @@ import collections
 import dataclasses
 import functools
 import os
+import weakref
 from typing import Tuple
 
 import jax
@@ -49,6 +50,7 @@ from repro.events import process as events_mod
 from repro.grid import powercap
 from repro.grid import signals as gsig
 from repro.kernels.power_topo import ops as topo_ops
+from repro.obs import phases
 from repro.obs import timing as obs_timing
 from repro.power import losses as plosses
 from repro.power import model as pmodel
@@ -95,7 +97,7 @@ def init_state(system: SystemConfig, table: T.JobTable, t0: float,
     if accounts is None:
         accounts = T.AccountStats.zeros(num_accounts)
     else:
-        # the ledger is embedded in the scan carry, which the AOT runners
+        # the ledger is embedded in the scan carry, which the runners
         # donate — copy so the caller's warm-start buffers survive the run
         accounts = jax.tree_util.tree_map(jnp.copy, accounts)
     # prepopulated jobs ran unthrottled before the window: work-time
@@ -122,19 +124,21 @@ def init_state(system: SystemConfig, table: T.JobTable, t0: float,
 def _prepare_and_arrivals(system: SystemConfig, table: T.JobTable,
                           st: T.SimState) -> T.SimState:
     """Phases (1)+(2): completions, node release, accounting, arrivals."""
-    t = st.t
-    done_now = (st.jstate == T.RUNNING) & (t >= st.end)
-    node_job = rm.release_done(st.node_job, done_now)
-    freed = jnp.sum(jnp.where(done_now, table.nodes, 0))
-    jstate = jnp.where(done_now, T.DONE, st.jstate)
-    accounts = acct_mod.fold_completions(system, table, st.accounts, done_now,
-                                         st.start, st.end, st.jenergy)
-    jstate = jnp.where((jstate == T.PENDING) & (table.submit <= t),
-                       T.QUEUED, jstate)
-    return dataclasses.replace(
-        st, jstate=jstate, node_job=node_job,
-        free_count=st.free_count + freed, accounts=accounts,
-        completed=st.completed + jnp.sum(done_now))
+    with jax.named_scope(phases.PREPARE):
+        t = st.t
+        done_now = (st.jstate == T.RUNNING) & (t >= st.end)
+        node_job = rm.release_done(st.node_job, done_now)
+        freed = jnp.sum(jnp.where(done_now, table.nodes, 0))
+        jstate = jnp.where(done_now, T.DONE, st.jstate)
+        accounts = acct_mod.fold_completions(system, table, st.accounts,
+                                             done_now, st.start, st.end,
+                                             st.jenergy)
+        jstate = jnp.where((jstate == T.PENDING) & (table.submit <= t),
+                           T.QUEUED, jstate)
+        return dataclasses.replace(
+            st, jstate=jstate, node_job=node_job,
+            free_count=st.free_count + freed, accounts=accounts,
+            completed=st.completed + jnp.sum(done_now))
 
 
 def _tick(system: SystemConfig, table: T.JobTable, st: T.SimState,
@@ -169,109 +173,115 @@ def _tick(system: SystemConfig, table: T.JobTable, st: T.SimState,
     t = st.t
     has_grid = grid is not None
     t_wb = None if wx is None else wx.t_wetbulb_c
-    # profiles are indexed by work-time progress, so a throttled job's
-    # trace plays at its dilated tempo instead of wall-clock time
-    job_pw = pmodel.job_node_power_elapsed(table, st.jstate, st.progress,
-                                           system.prof_dt)
-    node_pw = pmodel.node_power(system, table, st.node_job, job_pw)
-    running = st.jstate == T.RUNNING
+    with jax.named_scope(phases.POWER):
+        # profiles are indexed by work-time progress, so a throttled job's
+        # trace plays at its dilated tempo instead of wall-clock time
+        job_pw = pmodel.job_node_power_elapsed(table, st.jstate,
+                                               st.progress, system.prof_dt)
+        node_pw = pmodel.node_power(system, table, st.node_job, job_pw)
+        running = st.jstate == T.RUNNING
     if has_grid:
-        idle = system.power.idle_node_w
-        cap = powercap.enforce_cap(system, node_pw, cap_active)
-        p_it = cap.p_it
-        # DVFS only slows jobs with dynamic (above-idle) draw; a job at or
-        # below the idle floor keeps full speed (its power is untouched by
-        # throttle_power, so its runtime must be too)
-        c_job = jnp.where(running & (job_pw > idle), cap.c, 1.0)
-        job_pw = powercap.throttle_power(job_pw, idle, cap.c)
-        throttle = 1.0 - cap.c
-        cool_state, cool = cooling.step(system.cooling, st.cooling,
-                                        cap.group_heat, dt, t_wb,
-                                        setpoint_delta_c, cells_offline,
-                                        cells_failed)
+        with jax.named_scope(phases.POWER):
+            idle = system.power.idle_node_w
+            cap = powercap.enforce_cap(system, node_pw, cap_active)
+            p_it = cap.p_it
+            # DVFS only slows jobs with dynamic (above-idle) draw; a job at
+            # or below the idle floor keeps full speed (its power is
+            # untouched by throttle_power, so its runtime must be too)
+            c_job = jnp.where(running & (job_pw > idle), cap.c, 1.0)
+            job_pw = powercap.throttle_power(job_pw, idle, cap.c)
+            throttle = 1.0 - cap.c
+        with jax.named_scope(phases.COOLING):
+            cool_state, cool = cooling.step(system.cooling, st.cooling,
+                                            cap.group_heat, dt, t_wb,
+                                            setpoint_delta_c, cells_offline,
+                                            cells_failed)
     else:
         cap_active = T.INF
         throttle = jnp.float32(0.0)
         # fused path: hierarchical (node -> CDU -> hall) segment reduce +
         # CDU loop update in one pass; total IT power falls out of the
         # hall sums
-        cool_state, cool, p_it = cooling.step_from_node_power(
-            system.cooling, st.cooling, node_pw, dt, t_wb, setpoint_delta_c,
-            cells_offline, cells_failed)
-    n_racks = max(system.n_nodes // system.power.nodes_per_rack, 1)
-    p_in, p_loss = plosses.conversion(system.power, p_it, float(n_racks))
-    p_cool = cool.p_cooling
-    t_tower_ret = cool.t_tower_return
-    p_total = p_in + p_cool
-    pue = cooling.pue(p_it, p_loss, p_cool)
+        with jax.named_scope(phases.COOLING):
+            cool_state, cool, p_it = cooling.step_from_node_power(
+                system.cooling, st.cooling, node_pw, dt, t_wb,
+                setpoint_delta_c, cells_offline, cells_failed)
+    with jax.named_scope(phases.POWER):
+        n_racks = max(system.n_nodes // system.power.nodes_per_rack, 1)
+        p_in, p_loss = plosses.conversion(system.power, p_it, float(n_racks))
+    with jax.named_scope(phases.TELEMETRY):
+        p_cool = cool.p_cooling
+        t_tower_ret = cool.t_tower_return
+        p_total = p_in + p_cool
+        pue = cooling.pue(p_it, p_loss, p_cool)
 
-    job_e_step = jnp.where(
-        running, job_pw * table.nodes.astype(jnp.float32) * dt, 0.0)
-    jenergy = st.jenergy + job_e_step
+        job_e_step = jnp.where(
+            running, job_pw * table.nodes.astype(jnp.float32) * dt, 0.0)
+        jenergy = st.jenergy + job_e_step
 
-    if has_grid:
-        accounts = acct_mod.accrue_grid(table, st.accounts, job_e_step,
-                                        grid.carbon, grid.price)
-        # runtime dilation: a throttled step advances a job's work-time by
-        # only c*dt (each unit of work takes 1/c longer), so its projected
-        # end recedes by the shortfall dt*(1 - c). The two views agree:
-        # t >= end  <=>  progress >= wall.  A job throttled at c for its
-        # whole life runs 1/c times longer in total.
-        end = jnp.where(running & jnp.isfinite(st.end),
-                        st.end + dt * (1.0 - c_job), st.end)
-        progress = st.progress + jnp.where(running, c_job * dt, 0.0)
-        emissions = p_total * dt * grid.carbon / 3.6e6 * 1e-3  # g/kWh -> kg
-        cost = p_total * dt * grid.price / 3.6e6               # $/kWh
-    else:
-        accounts = st.accounts
-        end = st.end
-        progress = st.progress + jnp.where(running, dt, 0.0)
-        emissions = jnp.float32(0.0)
-        cost = jnp.float32(0.0)
+        if has_grid:
+            accounts = acct_mod.accrue_grid(table, st.accounts, job_e_step,
+                                            grid.carbon, grid.price)
+            # runtime dilation: a throttled step advances a job's work-time by
+            # only c*dt (each unit of work takes 1/c longer), so its projected
+            # end recedes by the shortfall dt*(1 - c). The two views agree:
+            # t >= end  <=>  progress >= wall.  A job throttled at c for its
+            # whole life runs 1/c times longer in total.
+            end = jnp.where(running & jnp.isfinite(st.end),
+                            st.end + dt * (1.0 - c_job), st.end)
+            progress = st.progress + jnp.where(running, c_job * dt, 0.0)
+            emissions = p_total * dt * grid.carbon / 3.6e6 * 1e-3  # kg
+            cost = p_total * dt * grid.price / 3.6e6               # $
+        else:
+            accounts = st.accounts
+            end = st.end
+            progress = st.progress + jnp.where(running, dt, 0.0)
+            emissions = jnp.float32(0.0)
+            cost = jnp.float32(0.0)
 
-    busy = jnp.float32(system.n_nodes) - st.free_count.astype(jnp.float32)
-    if ev_now is not None:
-        # down free nodes are parked at -2 (outside the -1 free pool), so
-        # they'd otherwise count as busy; utilization should count work
-        busy = busy - ev_now.nodes_down
-    H = system.cooling.n_halls
-    rec = T.StepRecord(
-        t=t, power_it=p_it, power_loss=p_loss, power_cooling=p_cool,
-        power_total=p_total, pue=pue, t_tower_return=t_tower_ret,
-        util=busy / system.n_nodes,
-        n_queued=jnp.sum(st.jstate == T.QUEUED).astype(jnp.float32),
-        n_running=jnp.sum(running).astype(jnp.float32),
-        emissions_kg=emissions, energy_cost=cost, cap_w=cap_active,
-        throttle_frac=throttle,
-        power_fan=cool.p_fan, power_pump=cool.p_pump,
-        q_reuse_w=cool.q_reuse_w, t_basin=cool.t_basin,
-        t_supply_max=cool.t_supply_max,
-        t_wetbulb=(jnp.float32(system.cooling.t_wetbulb_c) if wx is None
-                   else jnp.mean(wx.t_wetbulb_c)),
-        thermal_throttled=(jnp.float32(0.0) if thermal is None else
-                           thermal.overheat.astype(jnp.float32)),
-        # per-hall telemetry: the hall heat sums ARE the per-hall IT power
-        # (the cooling plant is fed the (throttled) IT draw per group)
-        power_it_hall=cool.q_hall_w, t_basin_hall=cool.t_basin_hall,
-        t_supply_max_hall=cool.t_supply_max_hall,
-        t_wetbulb_hall=cool.t_wetbulb_hall, cells_online=cool.cells_online,
-        nodes_down=(jnp.float32(0.0) if ev_now is None
-                    else ev_now.nodes_down),
-        n_killed=(jnp.float32(0.0) if ev_now is None else ev_now.n_killed),
-        overheat_hall=(jnp.zeros((H,), jnp.float32) if thermal is None
-                       else thermal.overheat_hall.astype(jnp.float32)))
+        busy = jnp.float32(system.n_nodes) - st.free_count.astype(jnp.float32)
+        if ev_now is not None:
+            # down free nodes are parked at -2 (outside the -1 free pool), so
+            # they'd otherwise count as busy; utilization should count work
+            busy = busy - ev_now.nodes_down
+        H = system.cooling.n_halls
+        rec = T.StepRecord(
+            t=t, power_it=p_it, power_loss=p_loss, power_cooling=p_cool,
+            power_total=p_total, pue=pue, t_tower_return=t_tower_ret,
+            util=busy / system.n_nodes,
+            n_queued=jnp.sum(st.jstate == T.QUEUED).astype(jnp.float32),
+            n_running=jnp.sum(running).astype(jnp.float32),
+            emissions_kg=emissions, energy_cost=cost, cap_w=cap_active,
+            throttle_frac=throttle,
+            power_fan=cool.p_fan, power_pump=cool.p_pump,
+            q_reuse_w=cool.q_reuse_w, t_basin=cool.t_basin,
+            t_supply_max=cool.t_supply_max,
+            t_wetbulb=(jnp.float32(system.cooling.t_wetbulb_c) if wx is None
+                       else jnp.mean(wx.t_wetbulb_c)),
+            thermal_throttled=(jnp.float32(0.0) if thermal is None else
+                               thermal.overheat.astype(jnp.float32)),
+            # per-hall telemetry: the hall heat sums ARE the per-hall IT power
+            # (the cooling plant is fed the (throttled) IT draw per group)
+            power_it_hall=cool.q_hall_w, t_basin_hall=cool.t_basin_hall,
+            t_supply_max_hall=cool.t_supply_max_hall,
+            t_wetbulb_hall=cool.t_wetbulb_hall, cells_online=cool.cells_online,
+            nodes_down=(jnp.float32(0.0) if ev_now is None
+                        else ev_now.nodes_down),
+            n_killed=(jnp.float32(0.0) if ev_now is None else ev_now.n_killed),
+            overheat_hall=(jnp.zeros((H,), jnp.float32) if thermal is None
+                           else thermal.overheat_hall.astype(jnp.float32)))
 
-    new = dataclasses.replace(
-        st, t=t + dt, step=st.step + 1, end=end, progress=progress,
-        jenergy=jenergy, accounts=accounts, cooling=cool_state,
-        energy_total=st.energy_total + p_total * dt,
-        energy_it=st.energy_it + p_it * dt,
-        energy_loss=st.energy_loss + p_loss * dt,
-        emissions_kg=st.emissions_kg + emissions,
-        energy_cost=st.energy_cost + cost,
-        energy_cooling=st.energy_cooling + p_cool * dt,
-        heat_reuse_j=st.heat_reuse_j + cool.q_reuse_w * dt)
-    return new, rec
+        new = dataclasses.replace(
+            st, t=t + dt, step=st.step + 1, end=end, progress=progress,
+            jenergy=jenergy, accounts=accounts, cooling=cool_state,
+            energy_total=st.energy_total + p_total * dt,
+            energy_it=st.energy_it + p_it * dt,
+            energy_loss=st.energy_loss + p_loss * dt,
+            emissions_kg=st.emissions_kg + emissions,
+            energy_cost=st.energy_cost + cost,
+            energy_cooling=st.energy_cooling + p_cool * dt,
+            heat_reuse_j=st.heat_reuse_j + cool.q_reuse_w * dt)
+        return new, rec
 
 
 def engine_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
@@ -288,18 +298,21 @@ def engine_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
     if events is not None:
         # phase (2b): draw failures/repairs, kill hit jobs, update the
         # availability map; DR cap steps are evaluated at the same point
-        st, ev_now = events_mod.apply_failures(events, system, table, st,
-                                               scen)
-        dr = events_mod.dr_now(scen, st.t)
-        cells_failed = ev_now.cells_failed_hall
+        with jax.named_scope(phases.FAILURES):
+            st, ev_now = events_mod.apply_failures(events, system, table,
+                                                   st, scen)
+            dr = events_mod.dr_now(scen, st.t)
+            cells_failed = ev_now.cells_failed_hall
     else:
         ev_now = None
         dr = None
         cells_failed = 0.0
-    wx = None if weather is None else wsig.at_step(weather, st.step)
-    # cooling-pressure signals for the thermal_aware policy + admission gate
-    thermal = cooling.thermal_now(system.cooling, st.cooling,
-                                  scen.setpoint_delta_c)
+    with jax.named_scope(phases.QUEUE_ORDER):
+        wx = None if weather is None else wsig.at_step(weather, st.step)
+        # cooling-pressure signals for the thermal_aware policy + admission
+        # gate
+        thermal = cooling.thermal_now(system.cooling, st.cooling,
+                                      scen.setpoint_delta_c)
     if signals is None:
         # no grid layer: skip the admission power pass and cap machinery
         # (demand-response needs the grid path — the CLI injects neutral
@@ -308,17 +321,18 @@ def engine_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
         return _tick(system, table, st, None, None, wx,
                      scen.setpoint_delta_c, thermal, scen.cells_offline,
                      cells_failed, ev_now)
-    grid = gsig.at_step(signals, st.step)
-    cap_active = grid.cap_w * scen.cap_scale
-    if dr is not None:
-        # an active demand-response event caps below the schedule
-        cap_active = jnp.minimum(cap_active, dr.cap_now_w)
-    # raw IT draw after completions: the cap-aware admission baseline
-    job_pw = pmodel.job_node_power_elapsed(table, st.jstate, st.progress,
-                                           system.prof_dt)
-    node_pw = pmodel.node_power(system, table, st.node_job, job_pw)
-    st = sched.schedule_step(system, table, st, scen, grid,
-                             proj_pw=pmodel.system_it_power(node_pw),
+    with jax.named_scope(phases.POWER):
+        grid = gsig.at_step(signals, st.step)
+        cap_active = grid.cap_w * scen.cap_scale
+        if dr is not None:
+            # an active demand-response event caps below the schedule
+            cap_active = jnp.minimum(cap_active, dr.cap_now_w)
+        # raw IT draw after completions: the cap-aware admission baseline
+        job_pw = pmodel.job_node_power_elapsed(table, st.jstate, st.progress,
+                                               system.prof_dt)
+        node_pw = pmodel.node_power(system, table, st.node_job, job_pw)
+        proj_pw = pmodel.system_it_power(node_pw)
+    st = sched.schedule_step(system, table, st, scen, grid, proj_pw=proj_pw,
                              thermal=thermal, dr=dr)
     return _tick(system, table, st, grid, cap_active, wx,
                  scen.setpoint_delta_c, thermal, scen.cells_offline,
@@ -351,20 +365,23 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
     keeps every knob neutral. Policy/backfill fields are ignored: the
     external peer IS the policy.
     """
-    grid = None if signals is None else gsig.at_step(signals, st.step)
-    wx = None if weather is None else wsig.at_step(weather, st.step)
+    with jax.named_scope(phases.QUEUE_ORDER):
+        grid = None if signals is None else gsig.at_step(signals, st.step)
+        wx = None if weather is None else wsig.at_step(weather, st.step)
     setpoint_delta = 0.0 if scen is None else scen.setpoint_delta_c
     cells_offline = 0.0 if scen is None else scen.cells_offline
     cap_scale = 1.0 if scen is None else scen.cap_scale
     st = _prepare_and_arrivals(system, table, st)
-    thermal = cooling.thermal_now(system.cooling, st.cooling, setpoint_delta)
-    thermal_ok = ~thermal.overheat
     hall_aware = system.cooling.n_halls > 1
-    if hall_aware:
-        order_nodes, node_ok, free_ok0 = sched.hall_placement_plan(
-            system, st, thermal, is_replay=False)
-    else:
-        free_ok0 = st.free_count
+    with jax.named_scope(phases.QUEUE_ORDER):
+        thermal = cooling.thermal_now(system.cooling, st.cooling,
+                                      setpoint_delta)
+        thermal_ok = ~thermal.overheat
+        if hall_aware:
+            order_nodes, node_ok, free_ok0 = sched.hall_placement_plan(
+                system, st, thermal, is_replay=False)
+        else:
+            free_ok0 = st.free_count
 
     def body(i, carry):
         node_job, jstate, start, end, free_count, free_ok = carry
@@ -389,10 +406,11 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
         end = end.at[jj].set(jnp.where(can, st.t + table.wall[jj], end[jj]))
         return node_job, jstate, start, end, free_count, free_ok
 
-    carry = (st.node_job, st.jstate, st.start, st.end, st.free_count,
-             jnp.int32(free_ok0))
-    node_job, jstate, start, end, free_count, _ = jax.lax.fori_loop(
-        0, place_ids.shape[0], body, carry)
+    with jax.named_scope(phases.ADMISSION):
+        carry = (st.node_job, st.jstate, st.start, st.end, st.free_count,
+                 jnp.int32(free_ok0))
+        node_job, jstate, start, end, free_count, _ = jax.lax.fori_loop(
+            0, place_ids.shape[0], body, carry)
     st = dataclasses.replace(st, jstate=jstate, start=start, end=end,
                              node_job=node_job, free_count=free_count)
     return _tick(system, table, st, grid,
@@ -421,39 +439,111 @@ def _donate(*argnums: int) -> tuple:
     return tuple(argnums) if DONATE_CARRIES else ()
 
 
-@functools.partial(jax.jit, static_argnums=(0, 6, 7),
-                   donate_argnums=_donate(2))
-def _simulate_jit(system: SystemConfig, table: T.JobTable, st0: T.SimState,
-                  scen: T.Scenario, signals: gsig.GridSignals | None,
-                  weather: wsig.WeatherSignals | None, n_steps: int,
-                  events: "events_mod.EventConfig | None" = None):
-    # signals/weather=None are empty pytrees and events=None is a static
-    # arg: the no-grid / no-weather / no-failure fast paths in engine_step
-    # are selected at trace time and their machinery vanishes entirely
-    def body(st, _):
-        return engine_step(system, table, st, scen, signals, weather, events)
-    return jax.lax.scan(body, st0, None, length=n_steps)
+# Jitted-runner caches, keyed on (kind, system, n_steps, ...): one for the
+# sweep, sharded-sweep and segment runners (``simulate`` runs the segment
+# runner from a fresh carry), one for the static fast path's runners
+# (policy and backfill compiled in). Both are LRU-bounded: a long-lived
+# server (repro.serve) advancing many distinct segment lengths would
+# otherwise grow them without limit — least-recently-used entries are
+# evicted past ``SWEEP_CACHE_LIMIT`` (dropping a compiled runner is
+# safe: the next same-shape call re-jits and re-enters the cache).
+_SWEEP_CACHE: "collections.OrderedDict" = collections.OrderedDict()
+_STATIC_CACHE: dict = {}
+SWEEP_CACHE_LIMIT = 32
+# Monotonic hit/miss/eviction counters over both runner caches, always
+# on. A steady-state training loop should show hits only after
+# generation 0; ``ml.train`` snapshots the deltas per generation and the
+# run manifest embeds the totals.
+SWEEP_CACHE_STATS = {"hits": 0, "misses": 0, "evictions": 0}
 
 
-def _simulate_observed(system, table, st0, scen, signals, weather,
-                       n_steps: int, timer, events=None
-                       ) -> Tuple[T.SimState, T.StepRecord]:
-    """Opt-in observed run: AOT lower/compile so the jit **compile** phase
-    is a separate span from the scan **execute** phase (a plain jit call
-    fuses both into the first invocation, which is exactly the number a
-    flight recorder must split). Uncached on purpose — the observed path
-    is for one-shot CLI runs; hot callers never land here because they
-    install no timer."""
-    meta = {"system": system.name, "n_steps": int(n_steps)}
-    with timer.span("engine.lower", **meta):
-        lowered = _simulate_jit.lower(system, table, st0, scen, signals,
-                                      weather, n_steps, events)
-    with timer.span("engine.compile", **meta):
-        compiled = lowered.compile()
-    with timer.span("engine.scan", **meta):
-        out = jax.block_until_ready(
-            compiled(table, st0, scen, signals, weather))
-    return out
+def _cache_lookup(key, cache=None):
+    """LRU lookup in a runner cache (``_SWEEP_CACHE`` by default); bumps
+    the hit/miss counters."""
+    cache = _SWEEP_CACHE if cache is None else cache
+    fn = cache.pop(key, None)
+    if fn is not None:
+        cache[key] = fn                     # now the most recently used
+    SWEEP_CACHE_STATS["hits" if fn is not None else "misses"] += 1
+    return fn
+
+
+def _cache_store(key, fn, cache=None):
+    """Insert a runner, evicting least-recently-used entries past the
+    bound (counted in ``SWEEP_CACHE_STATS["evictions"]``)."""
+    cache = _SWEEP_CACHE if cache is None else cache
+    cache.pop(key, None)
+    cache[key] = fn
+    while len(cache) > SWEEP_CACHE_LIMIT:
+        del cache[next(iter(cache))]
+        SWEEP_CACHE_STATS["evictions"] += 1
+    return fn
+
+
+# Argument specs of each runner's first call (a cache hit records only
+# which runner ran), so ``last_runner_hlo`` can lower it again.
+_RUNNER_ARGS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_last_runner = None
+
+
+def _spec(x) -> jax.ShapeDtypeStruct:
+    """What a jitted call specializes on: shape, dtype, weak type, and
+    the sharding of an argument committed to its devices."""
+    aval = jax.typeof(x)
+    sharding = x.sharding if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(aval.shape, aval.dtype, sharding=sharding,
+                                weak_type=aval.weak_type)
+
+
+def _launch(fn, *args):
+    """Call runner ``fn`` inside an ``engine.launch`` span (until the
+    call returns: the device runs on asynchronously)."""
+    global _last_runner
+    if fn not in _RUNNER_ARGS:
+        _RUNNER_ARGS[fn] = jax.tree_util.tree_map(_spec, args)
+    _last_runner = weakref.ref(fn)
+    with obs_timing.span("engine.launch"):
+        return fn(*args)
+
+
+def last_runner_hlo() -> str | None:
+    """Optimized HLO text of the runner the engine called last, lowered
+    and compiled again from the argument specs of its first call (a hit
+    in the in-memory or persistent compilation cache); None before any
+    call. ``repro.obs.phases`` reads each instruction's phase from it."""
+    fn = _last_runner() if _last_runner is not None else None
+    if fn is None or fn not in _RUNNER_ARGS:
+        return None
+    return fn.lower(*_RUNNER_ARGS[fn]).compile().as_text()
+
+
+def _scan(n_steps: int, carry, step):
+    """``lax.scan`` of ``step`` (carry -> (carry, StepRecord)) over
+    ``n_steps``: (final carry, history). The scan's own per-step work,
+    its counter and the write of each history row, is telemetry;
+    ``engine_step`` names the phases of everything else."""
+    with jax.named_scope(phases.TELEMETRY):
+        return jax.lax.scan(lambda st, _: step(st), carry, None,
+                            length=int(n_steps))
+
+
+def _segment_fn(system: SystemConfig, n_steps: int, events=None):
+    """Cached jitted scan of ``n_steps`` engine steps from a given carry:
+    (table, carry, scen, signals, weather) -> (carry, history). The carry
+    is donated."""
+    key = ("segment", system, int(n_steps), events)
+    fn = _cache_lookup(key)
+    if fn is None:
+        # signals/weather=None are empty pytrees and events=None is static
+        # (closed over): the no-grid / no-weather / no-failure fast paths
+        # in engine_step are selected at trace time and their machinery
+        # vanishes entirely
+        @functools.partial(jax.jit, donate_argnums=_donate(1))
+        def fn(table_, carry_, scen_, signals_, weather_):
+            return _scan(n_steps, carry_, lambda st: engine_step(
+                system, table_, st, scen_, signals_, weather_, events))
+        _cache_store(key, fn)
+    return fn
 
 
 def simulate(system: SystemConfig, table: T.JobTable, scen: T.Scenario,
@@ -483,7 +573,7 @@ def simulate(system: SystemConfig, table: T.JobTable, scen: T.Scenario,
       carry: start from this scan carry instead of ``init_state`` (the
         resume-from-checkpoint path, repro.serve). ``t0``/``t1`` still
         size the window: ``n_steps = (t1 - t0) / dt`` steps run *from
-        the carry's own clock*.
+        the carry's own clock*. The carry is donated.
       events: static ``EventConfig`` enabling the stochastic failure +
         demand-response layer (repro.events); the per-scenario rates and
         seeds stay traced ``Scenario`` knobs. ``None`` = bit-identical
@@ -492,18 +582,15 @@ def simulate(system: SystemConfig, table: T.JobTable, scen: T.Scenario,
     Returns:
       (final SimState, StepRecord history with one row per step).
     """
-    n_steps = int(round((t1 - t0) / system.dt))
-    st0 = (init_state(system, table, t0, t1, accounts, num_accounts, events)
-           if carry is None else carry)
-    timer = obs_timing.current()
-    if timer is not None:
-        return _simulate_observed(system, table, st0, scen, signals,
-                                  weather, n_steps, timer, events)
-    return _simulate_jit(system, table, st0, scen, signals, weather, n_steps,
-                         events)
-
-
-_STATIC_CACHE: dict = {}
+    with obs_timing.span("engine.dispatch", entry="simulate"):
+        n_steps = int(round((t1 - t0) / system.dt))
+        if carry is None:
+            with obs_timing.span("engine.init_state"):
+                carry = init_state(system, table, t0, t1, accounts,
+                                   num_accounts, events)
+        with obs_timing.span("engine.runner"):
+            fn = _segment_fn(system, n_steps, events)
+        return _launch(fn, table, carry, scen, signals, weather)
 
 
 def simulate_static(system: SystemConfig, table: T.JobTable, policy: str,
@@ -520,79 +607,32 @@ def simulate_static(system: SystemConfig, table: T.JobTable, policy: str,
 
     ``carry`` starts the scan from an arbitrary checkpointed state
     instead of ``init_state`` (see ``simulate``)."""
-    n_steps = int(round((t1 - t0) / system.dt))
-    # keyword/default construction with raw Python values (-> static in
-    # the closure): every knob past policy/backfill takes its declared
-    # neutral default, so growing Scenario can never silently shift knobs
-    scen = T.Scenario(policy=T.POLICY_NAMES[policy],
-                      backfill=T.BACKFILL_NAMES[backfill])
-    key = (system, policy, backfill, n_steps, table.num_jobs,
-           table.prof_len, num_accounts, signals is None, weather is None)
-    fn = _STATIC_CACHE.get(key)
-    timer = obs_timing.current()
-    hit = fn is not None
-    if fn is None:
-        def run(table_, st0_, signals_, weather_):
-            def body(st, _):
-                return engine_step(system, table_, st, scen, signals_,
-                                   weather_)
-            return jax.lax.scan(body, st0_, None, length=n_steps)
-        fn = jax.jit(run, donate_argnums=_donate(1))
-        _STATIC_CACHE[key] = fn
-    st0 = (init_state(system, table, t0, t1, accounts, num_accounts)
-           if carry is None else carry)
-    if timer is None:
-        return fn(table, st0, signals, weather)
-    # observed path (opt-in): split compile from execute via AOT on a cache
-    # miss; a warm hit only times the scan. The AOT executable is NOT
-    # cached — the key above doesn't capture signal/weather array shapes,
-    # and jit (the cached object) re-specializes on those by itself.
-    timer.count("static_cache_hit" if hit else "static_cache_miss")
-    meta = {"system": system.name, "policy": policy, "n_steps": int(n_steps)}
-    if hit:
-        with timer.span("engine.scan", **meta):
-            return jax.block_until_ready(fn(table, st0, signals, weather))
-    with timer.span("engine.lower", **meta):
-        lowered = fn.lower(table, st0, signals, weather)
-    with timer.span("engine.compile", **meta):
-        compiled = lowered.compile()
-    with timer.span("engine.scan", **meta):
-        return jax.block_until_ready(compiled(table, st0, signals, weather))
-
-
-# Jitted-runner cache shared by the sweep, sharded-sweep and segment
-# paths, keyed on (kind, system, n_steps, ...). Bounded: a long-lived
-# server (repro.serve) advancing many distinct segment lengths would
-# otherwise grow it without limit — least-recently-used entries are
-# evicted past ``SWEEP_CACHE_LIMIT`` (dropping a compiled runner is
-# safe: the next same-shape call re-jits and re-enters the cache).
-_SWEEP_CACHE: "collections.OrderedDict" = collections.OrderedDict()
-SWEEP_CACHE_LIMIT = 32
-# Monotonic hit/miss/eviction counters over the jitted runner cache. A
-# steady-state training loop should show hits only after generation 0;
-# ``ml.train`` snapshots the deltas per generation and the run manifest
-# embeds the totals.
-SWEEP_CACHE_STATS = {"hits": 0, "misses": 0, "evictions": 0}
-
-
-def _cache_lookup(key):
-    """LRU lookup in the runner cache; bumps the hit/miss counters."""
-    fn = _SWEEP_CACHE.get(key)
-    if fn is not None:
-        _SWEEP_CACHE.move_to_end(key)
-    SWEEP_CACHE_STATS["hits" if fn is not None else "misses"] += 1
-    return fn
-
-
-def _cache_store(key, fn):
-    """Insert a runner, evicting least-recently-used entries past the
-    bound (counted in ``SWEEP_CACHE_STATS["evictions"]``)."""
-    _SWEEP_CACHE[key] = fn
-    _SWEEP_CACHE.move_to_end(key)
-    while len(_SWEEP_CACHE) > SWEEP_CACHE_LIMIT:
-        _SWEEP_CACHE.popitem(last=False)
-        SWEEP_CACHE_STATS["evictions"] += 1
-    return fn
+    with obs_timing.span("engine.dispatch", entry="simulate_static"):
+        n_steps = int(round((t1 - t0) / system.dt))
+        # keyword/default construction with raw Python values (-> static
+        # in the closure): every knob past policy/backfill takes its
+        # declared neutral default, so growing Scenario can never silently
+        # shift knobs
+        with obs_timing.span("engine.scenarios"):
+            scen = T.Scenario(policy=T.POLICY_NAMES[policy],
+                              backfill=T.BACKFILL_NAMES[backfill])
+        if carry is None:
+            with obs_timing.span("engine.init_state"):
+                carry = init_state(system, table, t0, t1, accounts,
+                                   num_accounts)
+        with obs_timing.span("engine.runner"):
+            key = ("static", system, policy, backfill, n_steps,
+                   table.num_jobs, table.prof_len, num_accounts,
+                   signals is None, weather is None)
+            fn = _cache_lookup(key, _STATIC_CACHE)
+            if fn is None:
+                def run(table_, st0_, signals_, weather_):
+                    return _scan(n_steps, st0_, lambda st: engine_step(
+                        system, table_, st, scen, signals_, weather_))
+                fn = _cache_store(key, jax.jit(run,
+                                               donate_argnums=_donate(1)),
+                                  _STATIC_CACHE)
+        return _launch(fn, table, carry, signals, weather)
 
 
 def _sweep_fn(system: SystemConfig, n_steps: int, w_axis, events=None):
@@ -610,10 +650,8 @@ def _sweep_fn(system: SystemConfig, n_steps: int, w_axis, events=None):
         @jax.jit
         def fn(table_, st0_, scen_, signals_, weather_):
             def one(scen1, weather1):
-                def body(st, _):
-                    return engine_step(system, table_, st, scen1, signals_,
-                                       weather1, events)
-                return jax.lax.scan(body, st0_, None, length=n_steps)
+                return _scan(n_steps, st0_, lambda st: engine_step(
+                    system, table_, st, scen1, signals_, weather1, events))
             return jax.vmap(one, in_axes=(0, w_axis))(scen_, weather_)
         _cache_store(key, fn)
     return fn
@@ -656,12 +694,16 @@ def simulate_sweep(system: SystemConfig, table: T.JobTable,
     through the traced ``failure_seed``/rate knobs — a (seed x rate x
     demand-response) risk grid is one compiled program.
     """
-    n_steps = int(round((t1 - t0) / system.dt))
-    st0 = init_state(system, table, t0, t1, accounts, num_accounts, events)
-    batched = T.stack_scenarios(scens)
-    weather_b, w_axis = _stack_weather(weather, scens)
-    run = _sweep_fn(system, n_steps, w_axis, events)
-    return run(table, st0, batched, signals, weather_b)
+    with obs_timing.span("engine.dispatch", entry="simulate_sweep"):
+        n_steps = int(round((t1 - t0) / system.dt))
+        with obs_timing.span("engine.init_state"):
+            st0 = init_state(system, table, t0, t1, accounts, num_accounts,
+                             events)
+        batched = T.stack_scenarios(scens)
+        weather_b, w_axis = _stack_weather(weather, scens)
+        with obs_timing.span("engine.runner"):
+            run = _sweep_fn(system, n_steps, w_axis, events)
+        return _launch(run, table, st0, batched, signals, weather_b)
 
 
 def sharded_sweep_fn(system: SystemConfig, n_steps: int, w_axis, events,
@@ -687,10 +729,8 @@ def sharded_sweep_fn(system: SystemConfig, n_steps: int, w_axis, events,
 
     def shard(table_s, st0_s, scen_s, signals_s, weather_s):
         def one(scen1, weather1):
-            def body(st, _):
-                return engine_step(system, table_s, st, scen1, signals_s,
-                                   weather1, events)
-            return jax.lax.scan(body, st0_s, None, length=n_steps)
+            return _scan(n_steps, st0_s, lambda st: engine_step(
+                system, table_s, st, scen1, signals_s, weather1, events))
         return jax.vmap(one, in_axes=(0, w_axis))(scen_s, weather_s)
 
     # check_vma=False: every loop carry inside engine_step (the scan's
@@ -719,12 +759,12 @@ def simulate_sweep_sharded(system: SystemConfig, table: T.JobTable,
     ``sharded_sweep_fn`` takes any other): each device scans its slice of
     the scenario batch with the job table, initial state and grid signals
     replicated — scenario rows never communicate, so the program contains
-    no collectives and scales linearly across hosts. Per-scenario weather (a list, possibly
-    hall-stacked — see ``cooling.weather.stack_halls``) is sharded with
-    the scenarios. The batch is padded to the device count by replicating
-    the last scenario; padded rows are sliced off the result. With a
-    single device this degenerates to exactly ``simulate_sweep`` (one
-    vmapped program, no sharding machinery).
+    no collectives and scales linearly across hosts. Per-scenario weather
+    (a list, possibly hall-stacked — see ``cooling.weather.stack_halls``)
+    is sharded with the scenarios. The batch is padded to the device
+    count by replicating the last scenario; padded rows are sliced off
+    the result. With a single device this degenerates to exactly
+    ``simulate_sweep`` (one vmapped program, no sharding machinery).
     """
     from repro.parallel import sharding as psh
 
@@ -733,18 +773,22 @@ def simulate_sweep_sharded(system: SystemConfig, table: T.JobTable,
     if n_dev <= 1:
         return simulate_sweep(system, table, scens, t0, t1, accounts,
                               num_accounts, signals, weather, events)
-    n_steps = int(round((t1 - t0) / system.dt))
-    st0 = init_state(system, table, t0, t1, accounts, num_accounts, events)
-    weather_b, w_axis = _stack_weather(weather, scens)
-    S = len(scens)
-    batched, _ = psh.pad_leading_axis(T.stack_scenarios(scens), n_dev)
-    if w_axis == 0:
-        weather_b, _ = psh.pad_leading_axis(weather_b, n_dev)
-    run = sharded_sweep_fn(system, n_steps, w_axis, events, mesh)
-    final, hist = run(table, st0, batched, signals, weather_b)
-    trim = lambda x: x[:S]
-    return (jax.tree_util.tree_map(trim, final),
-            jax.tree_util.tree_map(trim, hist))
+    with obs_timing.span("engine.dispatch", entry="simulate_sweep_sharded"):
+        n_steps = int(round((t1 - t0) / system.dt))
+        with obs_timing.span("engine.init_state"):
+            st0 = init_state(system, table, t0, t1, accounts, num_accounts,
+                             events)
+        weather_b, w_axis = _stack_weather(weather, scens)
+        S = len(scens)
+        batched, _ = psh.pad_leading_axis(T.stack_scenarios(scens), n_dev)
+        if w_axis == 0:
+            weather_b, _ = psh.pad_leading_axis(weather_b, n_dev)
+        with obs_timing.span("engine.runner"):
+            run = sharded_sweep_fn(system, n_steps, w_axis, events, mesh)
+        final, hist = _launch(run, table, st0, batched, signals, weather_b)
+        trim = lambda x: x[:S]
+        return (jax.tree_util.tree_map(trim, final),
+                jax.tree_util.tree_map(trim, hist))
 
 
 # ---------------------------------------------------------------------------
@@ -785,17 +829,10 @@ def simulate_segment(system: SystemConfig, table: T.JobTable,
     Returns:
       (carry after ``n_steps``, StepRecord history of the segment).
     """
-    key = ("segment", system, int(n_steps), events)
-    fn = _cache_lookup(key)
-    if fn is None:
-        @functools.partial(jax.jit, donate_argnums=_donate(1))
-        def fn(table_, carry_, scen_, signals_, weather_):
-            def body(st, _):
-                return engine_step(system, table_, st, scen_, signals_,
-                                   weather_, events)
-            return jax.lax.scan(body, carry_, None, length=int(n_steps))
-        _cache_store(key, fn)
-    return fn(table, carry, scen, signals, weather)
+    with obs_timing.span("engine.dispatch", entry="simulate_segment"):
+        with obs_timing.span("engine.runner"):
+            fn = _segment_fn(system, n_steps, events)
+        return _launch(fn, table, carry, scen, signals, weather)
 
 
 def simulate_segment_sweep(system: SystemConfig, table: T.JobTable,
@@ -825,20 +862,24 @@ def simulate_segment_sweep(system: SystemConfig, table: T.JobTable,
     if len(carries) != len(scens):
         raise ValueError(f"need one carry per scenario: "
                          f"{len(carries)} != {len(scens)}")
-    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *carries)
-    batched = T.stack_scenarios(list(scens))
-    key = ("segment_sweep", system, int(n_steps), events)
-    fn = _cache_lookup(key)
-    if fn is None:
-        # the stacked carries are a fresh jnp.stack buffer every call, so
-        # donating them is always safe and saves the B-branch copy
-        @functools.partial(jax.jit, donate_argnums=_donate(1))
-        def fn(table_, carries_, scen_, signals_, weather_):
-            def one(carry1, scen1):
-                def body(st, _):
-                    return engine_step(system, table_, st, scen1, signals_,
-                                       weather_, events)
-                return jax.lax.scan(body, carry1, None, length=int(n_steps))
-            return jax.vmap(one, in_axes=(0, 0))(carries_, scen_)
-        _cache_store(key, fn)
-    return fn(table, stacked, batched, signals, weather)
+    with obs_timing.span("engine.dispatch", entry="simulate_segment_sweep"):
+        with obs_timing.span("engine.init_state"):
+            stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                             *carries)
+        batched = T.stack_scenarios(list(scens))
+        with obs_timing.span("engine.runner"):
+            key = ("segment_sweep", system, int(n_steps), events)
+            fn = _cache_lookup(key)
+            if fn is None:
+                # the stacked carries are a fresh jnp.stack buffer every
+                # call, so donating them is always safe and saves the
+                # B-branch copy
+                @functools.partial(jax.jit, donate_argnums=_donate(1))
+                def fn(table_, carries_, scen_, signals_, weather_):
+                    def one(carry1, scen1):
+                        return _scan(n_steps, carry1, lambda st: engine_step(
+                            system, table_, st, scen1, signals_, weather_,
+                            events))
+                    return jax.vmap(one, in_axes=(0, 0))(carries_, scen_)
+                _cache_store(key, fn)
+        return _launch(fn, table, stacked, batched, signals, weather)

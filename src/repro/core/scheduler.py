@@ -34,6 +34,7 @@ from repro.core import resource_manager as rm
 from repro.core import types as T
 from repro.grid import signals as gsig
 from repro.kernels.power_topo.ref import group_ids
+from repro.obs import phases
 from repro.systems.config import SystemConfig
 
 
@@ -283,33 +284,34 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
     has_grid = grid is not None
     is_replay = scen.policy == T.POLICY_REPLAY
     hall_aware = thermal is not None and system.cooling.n_halls > 1
-    if hall_aware:
-        order_nodes, node_ok, free_ok0 = hall_placement_plan(
-            system, st, thermal, is_replay)
-    else:
-        order_nodes = node_ok = None
-        free_ok0 = st.free_count
-    thermal_ok = jnp.bool_(True) if thermal is None else ~thermal.overheat
-    if has_grid:
-        cap_active = grid.cap_w * scen.cap_scale
-        if dr is not None:
-            # an in-force DR event caps admission below the schedule
-            cap_active = jnp.minimum(cap_active, dr.cap_now_w)
-        # estimated power a job adds on start: first profile sample above
-        # the idle floor its nodes already draw
-        est_add_pw = jnp.maximum(
-            table.power_prof[:, 0] - system.power.idle_node_w, 0.0) * \
-            table.nodes.astype(jnp.float32)
-    if proj_pw is None:
-        proj_pw = jnp.float32(0.0)
-    order, _elig = queue_order(table, st, st.accounts, scen, grid, thermal)
-    static = isinstance(scen.backfill, int)
-    if static and scen.backfill != T.BF_EASY:
-        # static fast path: no reservation machinery needed
-        end_sorted = jnp.zeros((1,), jnp.float32)
-        cum_nodes = jnp.zeros((1,), jnp.int32)
-    else:
-        end_sorted, cum_nodes = release_profile(table, st)
+    with jax.named_scope(phases.QUEUE_ORDER):
+        if hall_aware:
+            order_nodes, node_ok, free_ok0 = hall_placement_plan(
+                system, st, thermal, is_replay)
+        else:
+            order_nodes = node_ok = None
+            free_ok0 = st.free_count
+        thermal_ok = jnp.bool_(True) if thermal is None else ~thermal.overheat
+        if has_grid:
+            cap_active = grid.cap_w * scen.cap_scale
+            if dr is not None:
+                # an in-force DR event caps admission below the schedule
+                cap_active = jnp.minimum(cap_active, dr.cap_now_w)
+            # estimated power a job adds on start: first profile sample above
+            # the idle floor its nodes already draw
+            est_add_pw = jnp.maximum(
+                table.power_prof[:, 0] - system.power.idle_node_w, 0.0) * \
+                table.nodes.astype(jnp.float32)
+        if proj_pw is None:
+            proj_pw = jnp.float32(0.0)
+        order, _elig = queue_order(table, st, st.accounts, scen, grid, thermal)
+        static = isinstance(scen.backfill, int)
+        if static and scen.backfill != T.BF_EASY:
+            # static fast path: no reservation machinery needed
+            end_sorted = jnp.zeros((1,), jnp.float32)
+            cum_nodes = jnp.zeros((1,), jnp.int32)
+        else:
+            end_sorted, cum_nodes = release_profile(table, st)
     n_nodes = system.n_nodes
     t = st.t
 
@@ -407,13 +409,14 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
                 blocked_any, head_blocked, head_capped,
                 shadow_t, shadow_extra)
 
-    carry = (st.node_job, st.jstate, st.start, st.end, st.free_count,
-             jnp.int32(free_ok0),
-             jnp.float32(proj_pw), jnp.bool_(False), jnp.bool_(False),
-             jnp.bool_(False), jnp.float32(jnp.inf), jnp.int32(0))
-    K = min(system.sched_budget, table.num_jobs)
-    (node_job, jstate, start, end, free_count,
-     *_rest) = jax.lax.fori_loop(0, K, body, carry)
+    with jax.named_scope(phases.ADMISSION):
+        carry = (st.node_job, st.jstate, st.start, st.end, st.free_count,
+                 jnp.int32(free_ok0),
+                 jnp.float32(proj_pw), jnp.bool_(False), jnp.bool_(False),
+                 jnp.bool_(False), jnp.float32(jnp.inf), jnp.int32(0))
+        K = min(system.sched_budget, table.num_jobs)
+        (node_job, jstate, start, end, free_count,
+         *_rest) = jax.lax.fori_loop(0, K, body, carry)
 
     return dataclasses.replace(st, jstate=jstate, start=start, end=end,
                                node_job=node_job, free_count=free_count)

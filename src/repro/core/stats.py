@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import types as T
+from repro.obs import timing as obs_timing
 from repro.systems.config import SystemConfig
 
 # job-size classes by node count (paper: "histogram of job size scheduled
@@ -17,6 +18,7 @@ from repro.systems.config import SystemConfig
 SIZE_EDGES = (1, 8, 128)  # small <8, medium <128, large >=128
 
 
+@obs_timing.span("stats.summarize")
 def summarize(system: SystemConfig, table: T.JobTable, final: T.SimState,
               hist: T.StepRecord) -> Dict[str, float]:
     """Reduce a run to its scalar summary metrics (paper §3.2.6).

@@ -12,6 +12,8 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 
+from repro.obs import timing as obs_timing
+
 # ---------------------------------------------------------------------------
 # Job lifecycle states (values matter: they are stored in int32 arrays).
 # ---------------------------------------------------------------------------
@@ -330,6 +332,7 @@ class Scenario:
     dr_cap_w: jnp.ndarray = 0.0          # f32[] cap level during the event (W)
 
     @staticmethod
+    @obs_timing.span("engine.scenarios")
     def make(policy: str | int, backfill: str | int = "none",
              acct_weight: float = 1.0, carbon_weight: float = 1.0,
              price_weight: float = 1.0, cap_scale: float = 1.0,
@@ -366,6 +369,7 @@ class Scenario:
             dr_cap_w=jnp.float32(dr_cap_w))
 
 
+@obs_timing.span("engine.scenarios")
 def stack_scenarios(scens: list) -> "Scenario":
     """Stack a list of Scenario leaves for vmapped sweeps. Leaves are
     broadcast to a common shape first, so scenarios that keep a vector

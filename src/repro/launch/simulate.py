@@ -307,17 +307,20 @@ def main(argv=None) -> int:
         recorder.event("run_start")
     timer = obs.SpanTimer(listener=recorder.span_listener
                           if recorder else None)
+    import jax
     if args.profile:
-        import jax
         jax.profiler.start_trace(args.profile)
 
     wall0 = time.perf_counter()
     with obs.use(timer):
         runs, bridge = _run(args, sys_, js, table, accounts, t0, t1,
                             cells_offline, recorder, weather)
+        # the engine returns once the device has its work: the wait for
+        # the results is the scan's time (engine.launch holds the compile)
+        with obs.span("engine.scan"):
+            jax.block_until_ready(runs)
     wall = time.perf_counter() - wall0
     if args.profile:
-        import jax
         jax.profiler.stop_trace()
         rep.info(f"profiler trace -> {args.profile}")
 
@@ -364,7 +367,8 @@ def main(argv=None) -> int:
         rep.result_json("bridge", bridge.stats())
     if recorder is not None:
         recorder.event("run_end", wall_s=wall)
-        counters = {"sweep_cache": dict(eng.SWEEP_CACHE_STATS)}
+        counters = {"sweep_cache": dict(eng.SWEEP_CACHE_STATS),
+                    "compiles": dict(obs.timing.COMPILE_STATS)}
         if bridge is not None:
             counters["bridge"] = bridge.stats()
         if sink is not None:

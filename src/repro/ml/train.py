@@ -344,7 +344,7 @@ def train(system: SystemConfig, table: T.JobTable, t0: float, t1: float,
              base_alpha[None].astype(np.float32)], 0)
         cache0 = dict(eng.SWEEP_CACHE_STATS)
         wall = time.perf_counter()
-        with obs_timing.maybe_span("train.generation", generation=gen):
+        with obs_timing.span("train.generation", generation=gen):
             finals, hists = _rollout(system, table, stack, t0, t1,
                                      backfill=backfill, scen_kw=scen_kw,
                                      signals=signals, weather=weather,

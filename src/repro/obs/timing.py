@@ -1,14 +1,27 @@
-"""Phase timing spans + latency histograms (the flight recorder's clock).
+"""Program spans, compile counters and latency histograms (the flight
+recorder's clock).
 
-A ``SpanTimer`` records named wall-clock spans (``with timer.span("engine
-.compile"): ...``) with arbitrary metadata and aggregates them into a
-JSON-able summary for the run manifest (``repro.obs.recorder``). The
-engine consults the *active* timer (``current()``): when one is installed
-via ``use(timer)``, ``engine.simulate`` / ``engine.simulate_static``
-split their jit **compile** phase from **execute** (AOT lower+compile, so
-the two phases are separately observable instead of fused into the first
-call), and ``ml.train`` wraps each generation. With no active timer the
-hot paths are untouched.
+``span(name)`` is the one way the program times a phase. Every span
+
+* opens a ``jax.profiler.TraceAnnotation`` of the same name, so it lands
+  on the host lines of any profiler capture (``simulate --profile``, a
+  benchmark trace) on the same clock as the device's operations;
+* records into ``RECENT``, a process-wide, lock-protected, bounded
+  in-memory ``SpanRecorder`` (spans from every thread, worker threads
+  included), with the name of the span it opened inside;
+* records into the thread's installed ``SpanTimer`` (``use(timer)``),
+  when there is one, which aggregates spans into the run manifest
+  (``repro.obs.recorder``).
+
+Installing a timer changes what is recorded, never the code path: the
+engine runs the same cached runner either way.
+
+One ``jax.monitoring`` listener, registered when this module is first
+imported, turns JAX's own compile events into ``engine.lower`` (trace
+plus lowering to MLIR) and ``engine.compile`` (backend compile, or a
+load from the persistent cache) spans, and counts them in
+``COMPILE_STATS``. Those two spans carry no profiler annotation: JAX
+reports them when they have ended.
 
 ``LatencyHistogram`` is the fixed-bucket (log-spaced) histogram behind
 the external bridge's per-poll latency counters
@@ -18,26 +31,33 @@ All durations in seconds.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import threading
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+import jax
+
 
 @dataclass
 class Span:
-    """One completed (or in-flight) timed phase."""
+    """One completed (or in-flight) timed phase. ``parent`` is the name
+    of the span that was open on the same thread when this one opened."""
     name: str
     t_start: float                 # clock() at entry (s)
     dur_s: float = 0.0             # filled at exit
     meta: dict = field(default_factory=dict)
+    parent: Optional[str] = None
 
     def as_dict(self) -> dict:
         d = {"name": self.name, "t_start": self.t_start,
              "dur_s": self.dur_s}
         if self.meta:
             d["meta"] = dict(self.meta)
+        if self.parent is not None:
+            d["parent"] = self.parent
         return d
 
 
@@ -59,18 +79,25 @@ class SpanTimer:
 
     @contextlib.contextmanager
     def span(self, name: str, **meta):
-        """Time a phase; the span is recorded even if the body raises."""
-        sp = Span(name=name, t_start=self.clock(), meta=meta)
+        """Time a phase on this timer alone; the span is recorded even if
+        the body raises. The program's spans go through the module-level
+        ``span``, which calls this for the installed timer."""
+        sp = Span(name=name, t_start=self.clock(), meta=meta,
+                  parent=_open_name())
         if self.listener is not None:
             self.listener("span_start", {"span": name, **meta})
         try:
             yield sp
         finally:
             sp.dur_s = self.clock() - sp.t_start
-            self.spans.append(sp)
-            if self.listener is not None:
-                self.listener("span_end",
-                              {"span": name, "dur_s": sp.dur_s, **meta})
+            self.add(sp)
+
+    def add(self, sp: Span) -> None:
+        """Record a span that has ended (and tell the listener)."""
+        self.spans.append(sp)
+        if self.listener is not None:
+            self.listener("span_end",
+                          {"span": sp.name, "dur_s": sp.dur_s, **sp.meta})
 
     def count(self, name: str, n: int = 1) -> None:
         """Bump a named event counter (e.g. a cache hit)."""
@@ -89,8 +116,32 @@ class SpanTimer:
         return {"spans": agg, "counters": dict(self.counts)}
 
 
+class SpanRecorder:
+    """The last ``limit`` spans of every thread, oldest first."""
+
+    def __init__(self, limit: int = 4096):
+        self._lock = threading.Lock()
+        self._spans: collections.deque = collections.deque(maxlen=limit)
+
+    def add(self, sp: Span) -> None:
+        with self._lock:
+            self._spans.append(sp)
+
+    def spans(self) -> List[Span]:
+        """A copy of the recorded spans, in the order they ended."""
+        with self._lock:
+            return list(self._spans)
+
+    def clear(self) -> None:
+        with self._lock:
+            self._spans.clear()
+
+
+RECENT = SpanRecorder()
+
+
 # ---------------------------------------------------------------------------
-# Active-timer registry (what the engine consults).
+# Active-timer registry and the program's spans.
 # ---------------------------------------------------------------------------
 _local = threading.local()
 
@@ -111,15 +162,91 @@ def use(timer: SpanTimer):
         _local.timer = prev
 
 
+def _open_stack() -> list:
+    stack = getattr(_local, "open", None)
+    if stack is None:
+        stack = _local.open = []
+    return stack
+
+
+def _open_name() -> Optional[str]:
+    stack = _open_stack()
+    return stack[-1] if stack else None
+
+
 @contextlib.contextmanager
-def maybe_span(name: str, **meta):
-    """Span on the active timer if one is installed; no-op otherwise."""
-    t = current()
-    if t is None:
-        yield None
-    else:
-        with t.span(name, **meta) as sp:
-            yield sp
+def span(name: str, **meta):
+    """Time a phase: a profiler annotation, a span in ``RECENT`` and, when
+    a timer is installed on this thread, a span on it (yielded; None
+    otherwise). Recorded even if the body raises."""
+    timer = current()
+    sp = Span(name=name, t_start=time.perf_counter(), meta=meta,
+              parent=_open_name())
+    timed = (contextlib.nullcontext() if timer is None
+             else timer.span(name, **meta))
+    stack = _open_stack()
+    try:
+        with timed as tsp, jax.profiler.TraceAnnotation(name):
+            stack.append(name)
+            try:
+                yield tsp
+            finally:
+                stack.pop()
+    finally:
+        sp.dur_s = time.perf_counter() - sp.t_start
+        RECENT.add(sp)
+
+
+# ---------------------------------------------------------------------------
+# Compile events.
+# ---------------------------------------------------------------------------
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+# Process totals: executables built or loaded from the persistent cache,
+# and the seconds spent building them.
+COMPILE_STATS = {"compiles": 0, "compile_s": 0.0, "lower_s": 0.0}
+_stats_lock = threading.Lock()
+
+
+def _record_done(name: str, start_unix: float, end_unix: float) -> None:
+    """Record a span JAX reports after it ended (times on ``time.time``)."""
+    now = time.perf_counter()
+    sp = Span(name=name, t_start=now - (time.time() - start_unix),
+              dur_s=end_unix - start_unix, parent=_open_name())
+    RECENT.add(sp)
+    timer = current()
+    if timer is not None:
+        timer.add(sp)
+
+
+def _on_compile_event(event: str, start: float, end: float, **_) -> None:
+    """``engine.lower`` runs from the start of the outermost trace that
+    precedes a lowering on this thread to the end of the lowering;
+    ``engine.compile`` is the backend's compile."""
+    if event == TRACE_EVENT:
+        last = getattr(_local, "trace", None)
+        # traces nest (a jitted function traces what it calls): keep the
+        # outermost, and drop one that ended before this one began
+        _local.trace = ((start, end) if last is None or start > last[1]
+                        else (min(last[0], start), max(last[1], end)))
+    elif event == LOWER_EVENT:
+        last = getattr(_local, "trace", None)
+        _local.trace = None
+        if last is not None:
+            start = min(start, last[0])
+        with _stats_lock:
+            COMPILE_STATS["lower_s"] += end - start
+        _record_done("engine.lower", start, end)
+    elif event == COMPILE_EVENT:
+        with _stats_lock:
+            COMPILE_STATS["compiles"] += 1
+            COMPILE_STATS["compile_s"] += end - start
+        _record_done("engine.compile", start, end)
+
+
+jax.monitoring.register_event_time_span_listener(_on_compile_event)
 
 
 # ---------------------------------------------------------------------------

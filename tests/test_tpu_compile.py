@@ -26,6 +26,7 @@ from repro.core import types as T
 from repro.cooling import model as cool
 from repro.datasets import loaders
 from repro.kernels.power_topo import ops
+from repro.obs import phases
 from repro.parallel import sharding as psh
 from repro.systems.config import get_system
 
@@ -77,13 +78,20 @@ def test_frontier_scan_compiles_for_one_chip(topo, frontier):
     system, table, st0 = frontier
     one = SingleDeviceSharding(topo.devices[0])
     n_steps = int(DAY_S / system.dt)
-    compiled = eng._simulate_jit.lower(
-        system, _shapes(table, one), _shapes(st0, one),
-        _shapes(T.Scenario.make("fcfs", "easy"), one), None, None,
-        n_steps, None).compile()
+    compiled = eng._segment_fn(system, n_steps).lower(
+        _shapes(table, one), _shapes(st0, one),
+        _shapes(T.Scenario.make("fcfs", "easy"), one), None, None).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2**30
     assert n_steps == 5760
+    # the chip's compiler keeps the phase scopes, so a trace's device time
+    # reads per phase; the node cumsum it rewrites without a name stays
+    # with the admission loop that runs it
+    ins = phases.instructions(compiled.as_text())
+    assert {i.phase for i in ins if i.depth > 0} >= set(phases.PHASES) - {
+        phases.FAILURES}
+    assert {i.phase for i in ins if i.opcode == "reduce-window"
+            and i.depth > 1} == {phases.ADMISSION}
 
 
 @pytest.mark.parametrize("kernel", ["group_power", "fused_cooling_hier"])
