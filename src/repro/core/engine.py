@@ -8,7 +8,9 @@ Main loop per step (paper's four well-defined steps):
                      thermally throttled when cooling loses its setpoint;
   (4) tick        -- power model (or measured-telemetry replay when the
                      table carries a ``power_profile`` channel —
-                     repro.traces) -> DVFS cap enforcement (repro.grid) ->
+                     repro.traces), summed per CDU group from the per-job
+                     powers and ``SimState.job_group_nodes`` -> DVFS cap
+                     enforcement (repro.grid) ->
                      conversion losses -> transient cooling loop
                      (repro.cooling, weather-driven) -> telemetry row;
                      advance time.
@@ -49,7 +51,6 @@ from repro.core import types as T
 from repro.events import process as events_mod
 from repro.grid import powercap
 from repro.grid import signals as gsig
-from repro.kernels.power_topo import ops as topo_ops
 from repro.obs import phases
 from repro.obs import timing as obs_timing
 from repro.power import losses as plosses
@@ -93,6 +94,9 @@ def init_state(system: SystemConfig, table: T.JobTable, t0: float,
     end = jnp.where(running0, rec_end, jnp.inf)
     node_job = rm.prepopulate(system.n_nodes, table.first_node, table.nodes,
                               running0)
+    job_group_nodes = rm.prepopulate_groups(
+        system.n_nodes, system.cooling.n_groups, table.first_node,
+        table.nodes, running0)
     free_count = jnp.sum((node_job == -1).astype(jnp.int32))
     if accounts is None:
         accounts = T.AccountStats.zeros(num_accounts)
@@ -108,7 +112,8 @@ def init_state(system: SystemConfig, table: T.JobTable, t0: float,
         t=jnp.float32(t0), step=jnp.int32(0), jstate=jstate, start=start,
         end=end, progress=progress,
         jenergy=jnp.zeros((J,), jnp.float32), node_job=node_job,
-        free_count=free_count, accounts=accounts,
+        free_count=free_count, job_group_nodes=job_group_nodes,
+        accounts=accounts,
         cooling=cooling.init_state(system.cooling),
         energy_total=jnp.float32(0.0), energy_it=jnp.float32(0.0),
         energy_loss=jnp.float32(0.0), completed=jnp.float32(0.0),
@@ -156,9 +161,9 @@ def _tick(system: SystemConfig, table: T.JobTable, st: T.SimState,
     common factor c and the affected jobs' remaining runtime dilates by 1/c
     for this step — capping trades completion latency for peak power.
     ``grid is None`` is compile-time "no grid layer": no accrual, no
-    dilation, and the node->CDU segment reduction fuses with the cooling
-    loop update (repro.kernels.power_topo.fused_cooling) — the seed
-    engine's exact cost.
+    dilation, no cap. Either way the cooling plant takes per-CDU heat
+    summed from the per-job powers and the per-group occupancy
+    (``repro.power.model.group_power``); no per-node power is formed.
 
     ``wx`` carries the ambient conditions for this step (°C, scalar or
     per-hall f32[H]); ``None`` is compile-time "no weather trace" and the
@@ -178,12 +183,14 @@ def _tick(system: SystemConfig, table: T.JobTable, st: T.SimState,
         # trace plays at its dilated tempo instead of wall-clock time
         job_pw = pmodel.job_node_power_elapsed(table, st.jstate,
                                                st.progress, system.prof_dt)
-        node_pw = pmodel.node_power(system, table, st.node_job, job_pw)
+        occ = pmodel.group_occupancy(st.job_group_nodes, st.jstate)
         running = st.jstate == T.RUNNING
     if has_grid:
         with jax.named_scope(phases.POWER):
             idle = system.power.idle_node_w
-            cap = powercap.enforce_cap(system, node_pw, cap_active)
+            floor_g, dyn_g = powercap.group_split(system, occ, job_pw)
+            cap = powercap.enforce_cap_groups(system, floor_g, dyn_g,
+                                              cap_active)
             p_it = cap.p_it
             # DVFS only slows jobs with dynamic (above-idle) draw; a job at
             # or below the idle floor keeps full speed (its power is
@@ -192,20 +199,18 @@ def _tick(system: SystemConfig, table: T.JobTable, st: T.SimState,
             job_pw = powercap.throttle_power(job_pw, idle, cap.c)
             throttle = 1.0 - cap.c
         with jax.named_scope(phases.COOLING):
-            cool_state, cool = cooling.step(system.cooling, st.cooling,
-                                            cap.group_heat, dt, t_wb,
-                                            setpoint_delta_c, cells_offline,
-                                            cells_failed)
+            group_heat = cap.group_heat
     else:
         cap_active = T.INF
         throttle = jnp.float32(0.0)
-        # fused path: hierarchical (node -> CDU -> hall) segment reduce +
-        # CDU loop update in one pass; total IT power falls out of the
-        # hall sums
-        with jax.named_scope(phases.COOLING):
-            cool_state, cool, p_it = cooling.step_from_node_power(
-                system.cooling, st.cooling, node_pw, dt, t_wb,
-                setpoint_delta_c, cells_offline, cells_failed)
+        with jax.named_scope(phases.POWER):
+            group_heat = pmodel.group_power(system, occ, job_pw)
+            p_it = jnp.sum(group_heat)
+    with jax.named_scope(phases.COOLING):
+        cool_state, cool = cooling.step(system.cooling, st.cooling,
+                                        group_heat, dt, t_wb,
+                                        setpoint_delta_c, cells_offline,
+                                        cells_failed)
     with jax.named_scope(phases.POWER):
         n_racks = max(system.n_nodes // system.power.nodes_per_rack, 1)
         p_in, p_loss = plosses.conversion(system.power, p_it, float(n_racks))
@@ -330,8 +335,8 @@ def engine_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
         # raw IT draw after completions: the cap-aware admission baseline
         job_pw = pmodel.job_node_power_elapsed(table, st.jstate, st.progress,
                                                system.prof_dt)
-        node_pw = pmodel.node_power(system, table, st.node_job, job_pw)
-        proj_pw = pmodel.system_it_power(node_pw)
+        occ = pmodel.group_occupancy(st.job_group_nodes, st.jstate)
+        proj_pw = jnp.sum(pmodel.group_power(system, occ, job_pw))
     st = sched.schedule_step(system, table, st, scen, grid, proj_pw=proj_pw,
                              thermal=thermal, dr=dr)
     return _tick(system, table, st, grid, cap_active, wx,
@@ -378,10 +383,12 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
                                       setpoint_delta)
         thermal_ok = ~thermal.overheat
         if hall_aware:
-            order_nodes, node_ok, free_ok0 = sched.hall_placement_plan(
-                system, st, thermal, is_replay=False)
+            order_nodes, node_ok, free_ok0, group_pos = \
+                sched.hall_placement_plan(system, st, thermal,
+                                          is_replay=False)
         else:
             free_ok0 = st.free_count
+            group_pos = jnp.arange(system.cooling.n_groups, dtype=jnp.int32)
 
     def body(i, carry):
         node_job, jstate, start, end, free_count, free_ok = carry
@@ -411,8 +418,12 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
                  jnp.int32(free_ok0))
         node_job, jstate, start, end, free_count, _ = jax.lax.fori_loop(
             0, place_ids.shape[0], body, carry)
+        job_group_nodes = rm.record_placements(
+            st.job_group_nodes, st.node_job, group_pos, place_ids, st.jstate,
+            jstate, table.nodes)
     st = dataclasses.replace(st, jstate=jstate, start=start, end=end,
-                             node_job=node_job, free_count=free_count)
+                             node_job=node_job, free_count=free_count,
+                             job_group_nodes=job_group_nodes)
     return _tick(system, table, st, grid,
                  None if grid is None else grid.cap_w * cap_scale, wx,
                  setpoint_delta, thermal, cells_offline)

@@ -33,7 +33,7 @@ from repro.cooling import model as cmodel
 from repro.core import resource_manager as rm
 from repro.core import types as T
 from repro.grid import signals as gsig
-from repro.kernels.power_topo.ref import group_ids
+from repro.kernels.power_topo.ref import group_ids, group_sizes
 from repro.obs import phases
 from repro.systems.config import SystemConfig
 
@@ -212,10 +212,12 @@ def hall_placement_plan(system: SystemConfig, st: T.SimState,
     H-element sort plus an O(N) scatter — no per-step N·log N sort inside
     the scan (H is tens, N up to ~160k).
 
-    Returns (order i32[N], node_ok bool[N], free_ok i32[]): the
-    preference permutation, which nodes sit in a non-overheated hall, and
-    how many of those are currently free (the per-job admission budget —
-    a job may start iff it fits inside ``free_ok``).
+    Returns (order i32[N], node_ok bool[N], free_ok i32[], group_pos
+    i32[G]): the preference permutation, which nodes sit in a
+    non-overheated hall, how many of those are currently free (the
+    per-job admission budget — a job may start iff it fits inside
+    ``free_ok``), and where each CDU group's first node falls in the
+    order (``resource_manager.placement_counts``).
     """
     node_hall_np, sizes_np, first_np = _hall_spans(system)
     node_hall = jnp.asarray(node_hall_np)
@@ -237,7 +239,13 @@ def hall_placement_plan(system: SystemConfig, st: T.SimState,
     pos = out_start[node_hall] + (idx - first[node_hall])
     order = jnp.zeros_like(idx).at[pos].set(idx)
     free_ok = jnp.sum(((st.node_job == -1) & node_ok).astype(jnp.int32))
-    return order, node_ok, free_ok
+    # groups lie whole inside halls: a group's first node moves with its
+    # hall's span (empty trailing groups hold no free node; any position)
+    g_sizes = group_sizes(system.n_nodes, system.cooling.n_groups)
+    g_first = np.minimum(np.cumsum(g_sizes) - g_sizes, system.n_nodes - 1)
+    g_hall = node_hall_np[g_first]
+    group_pos = out_start[g_hall] + jnp.asarray(g_first - first_np[g_hall])
+    return order, node_ok, free_ok, group_pos
 
 
 # ---------------------------------------------------------------------------
@@ -286,11 +294,12 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
     hall_aware = thermal is not None and system.cooling.n_halls > 1
     with jax.named_scope(phases.QUEUE_ORDER):
         if hall_aware:
-            order_nodes, node_ok, free_ok0 = hall_placement_plan(
+            order_nodes, node_ok, free_ok0, group_pos = hall_placement_plan(
                 system, st, thermal, is_replay)
         else:
             order_nodes = node_ok = None
             free_ok0 = st.free_count
+            group_pos = jnp.arange(system.cooling.n_groups, dtype=jnp.int32)
         thermal_ok = jnp.bool_(True) if thermal is None else ~thermal.overheat
         if has_grid:
             cap_active = grid.cap_w * scen.cap_scale
@@ -417,6 +426,10 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
         K = min(system.sched_budget, table.num_jobs)
         (node_job, jstate, start, end, free_count,
          *_rest) = jax.lax.fori_loop(0, K, body, carry)
+        job_group_nodes = rm.record_placements(
+            st.job_group_nodes, st.node_job, group_pos, order[:K], st.jstate,
+            jstate, table.nodes)
 
     return dataclasses.replace(st, jstate=jstate, start=start, end=end,
-                               node_job=node_job, free_count=free_count)
+                               node_job=node_job, free_count=free_count,
+                               job_group_nodes=job_group_nodes)

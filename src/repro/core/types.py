@@ -216,6 +216,13 @@ class SimState:
     jenergy: jnp.ndarray    # f32[J] accumulated job energy (J)
     node_job: jnp.ndarray   # i32[N] job id occupying each node, -1 when free
     free_count: jnp.ndarray  # i32[] number of free nodes
+    # i32[G, J] nodes each job holds in each CDU group (the node map's
+    # per-group summary, written where a job is placed and never cleared:
+    # a column is read only while its job is RUNNING, and a job leaves
+    # RUNNING exactly when its nodes are freed). Group-major so the job
+    # axis lies on the lanes. Power and heat per CDU come from it
+    # (repro.power.model.group_power) without forming per-node power.
+    job_group_nodes: jnp.ndarray
     accounts: AccountStats
     cooling: CoolingState
     # global accumulators

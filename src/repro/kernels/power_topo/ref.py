@@ -49,6 +49,12 @@ def group_ids(n_nodes: int, n_groups: int) -> np.ndarray:
     return np.minimum(idx // span, n_groups - 1)
 
 
+def group_sizes(n_nodes: int, n_groups: int) -> np.ndarray:
+    """i32[G] nodes in each CDU group (host numpy, like ``group_ids``)."""
+    return np.bincount(group_ids(n_nodes, n_groups),
+                       minlength=n_groups).astype(np.int32)
+
+
 def segment_dot(x: jnp.ndarray, one_hot: jnp.ndarray) -> jnp.ndarray:
     """``x @ one_hot`` at full f32 precision: the segment sum behind every
     one-hot reduction here. The TPU's default f32 matmul rounds its

@@ -7,6 +7,12 @@ Per-node IT power comes either from the job's recorded per-node power trace
 for missing samples, or from a scalar per-job average (summary datasets:
 Fugaku, Lassen, Adastra). Idle nodes draw ``idle_node_w``.
 
+The engine sums power per CDU group straight from the per-job values and
+the per-group occupancy ``SimState.job_group_nodes`` (``group_power``):
+J x G dense work, where spreading per-job power over the N nodes would
+be a gather of N elements. ``node_power`` is that per-node map, kept as
+the oracle the group sums are tested against.
+
 Telemetry replay (repro.traces): when the table carries a measured
 ``power_profile`` channel, jobs with a measurement play it back verbatim —
 the scan gathers the recorded sample at the job's work-time index instead
@@ -20,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import types as T
+from repro.kernels.power_topo.ref import group_sizes
 from repro.systems.config import SystemConfig
 
 
@@ -83,5 +90,21 @@ def node_power(system: SystemConfig, table: T.JobTable, node_job: jnp.ndarray,
     return jnp.where(occupied, p, system.power.idle_node_w)
 
 
-def system_it_power(node_pw: jnp.ndarray) -> jnp.ndarray:
-    return jnp.sum(node_pw)
+def group_occupancy(job_group_nodes: jnp.ndarray,
+                    jstate: jnp.ndarray) -> jnp.ndarray:
+    """f32[G, J] nodes each RUNNING job holds in each CDU group (other
+    jobs' columns, stale since their release, read 0)."""
+    running = jstate == T.RUNNING
+    return jnp.where(running, job_group_nodes, 0).astype(jnp.float32)
+
+
+def group_power(system: SystemConfig, occ: jnp.ndarray,
+                job_pw: jnp.ndarray) -> jnp.ndarray:
+    """IT power per CDU group -> f32[G]: the group's nodes no running job
+    holds at ``idle_node_w`` plus each job's per-node power ``job_pw``
+    (f32[J]) times its nodes there (``occ``, from ``group_occupancy``).
+    Full f32: an elementwise product and a sum over jobs."""
+    n_g = group_sizes(system.n_nodes, system.cooling.n_groups)
+    idle_nodes = n_g - jnp.sum(occ, axis=-1)
+    return system.power.idle_node_w * idle_nodes + jnp.sum(occ * job_pw,
+                                                           axis=-1)

@@ -1,4 +1,6 @@
 """Unit tests for policy keys, queue ordering, EASY shadow machinery."""
+import dataclasses
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -39,9 +41,9 @@ def test_queue_order_puts_eligible_first(system):
     table = make_table(system)
     st = eng.init_state(system, table, 0.0, 7200.0)
     # force a known time so some jobs are queued
-    st = T.SimState(**{**vars(st), "t": jnp.float32(1800.0),
-                       "jstate": jnp.where(table.submit <= 1800.0,
-                                           T.QUEUED, T.PENDING)})
+    st = dataclasses.replace(st, t=jnp.float32(1800.0),
+                             jstate=jnp.where(table.submit <= 1800.0,
+                                              T.QUEUED, T.PENDING))
     order, elig = sched.queue_order(table, st, st.accounts,
                                     T.Scenario.make("fcfs"))
     order = np.asarray(order)
@@ -71,9 +73,11 @@ def test_shadow_time_computation(system):
                            "nodes": jnp.asarray(nodes, jnp.int32),
                            "limit": jnp.asarray(limit)})
     st = eng.init_state(system, table2, 0.0, 7200.0)
-    st = T.SimState(**{**vars(st), "jstate": jstate,
-                       "start": jnp.where(end < jnp.inf, 0.0, jnp.inf),
-                       "end": end})
+    # only the release profile is read: the node map and its per-group
+    # summary (``job_group_nodes``) stay as init_state left them
+    st = dataclasses.replace(st, jstate=jstate,
+                             start=jnp.where(end < jnp.inf, 0.0, jnp.inf),
+                             end=end)
     # release profile uses start+limit as the EASY estimate; set limit=end
     limit[:3] = [100.0, 200.0, 300.0]
     table3 = T.JobTable(**{**vars(table2), "limit": jnp.asarray(

@@ -252,10 +252,12 @@ def test_decode_rejects_wrong_shape_and_version(template):
     mangled["leaves"]["t"]["shape"] = [3]
     with pytest.raises(snap.SnapshotError):
         snap.decode_carry(mangled, template)
-    dropped = json.loads(json.dumps(good))
-    del dropped["leaves"]["node_job"]
-    with pytest.raises(snap.SnapshotError, match="node_job"):
-        snap.decode_carry(dropped, template)
+    # the node map and its per-group summary are both part of the carry
+    for leaf in ("node_job", "job_group_nodes"):
+        dropped = json.loads(json.dumps(good))
+        del dropped["leaves"][leaf]
+        with pytest.raises(snap.SnapshotError, match=leaf):
+            snap.decode_carry(dropped, template)
 
 
 def test_scenario_delta_rejects_unknown_knobs():

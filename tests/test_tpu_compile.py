@@ -15,9 +15,11 @@ once. The fixture skips only where jaxlib has no TPU support installed;
 any other failure to describe the chip fails the tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
@@ -92,6 +94,30 @@ def test_frontier_scan_compiles_for_one_chip(topo, frontier):
         phases.FAILURES}
     assert {i.phase for i in ins if i.opcode == "reduce-window"
             and i.depth > 1} == {phases.ADMISSION}
+    # power and heat per CDU come from the per-group occupancy: no gather
+    # spreads a per-job value over the nodes there
+    assert not node_wide_gathers(compiled.as_text(), system.n_nodes,
+                                 {phases.POWER, phases.COOLING})
+
+
+_GATHER = re.compile(r"=\s*f32\[([\d,]*)\]\S*\s+gather\(")
+
+
+def node_wide_gathers(text, n_nodes, phase_set):
+    """Gathers (fused or not) named in one of ``phase_set`` whose f32
+    output has a multiple of ``n_nodes`` elements: per-node values, or
+    S rows of them under a vmap."""
+    found = []
+    for line in text.splitlines():
+        m = _GATHER.search(line)
+        if m is None:
+            continue
+        size = int(np.prod([int(d) for d in m.group(1).split(",") if d]))
+        op = re.search(r'op_name="([^"]*)"', line)
+        if (size % n_nodes == 0 and op
+                and phases.phase_of(op.group(1)) in phase_set):
+            found.append(line.strip()[:160])
+    return found
 
 
 @pytest.mark.parametrize("kernel", ["group_power", "fused_cooling_hier"])
