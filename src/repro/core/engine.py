@@ -94,6 +94,10 @@ def init_state(system: SystemConfig, table: T.JobTable, t0: float,
     end = jnp.where(running0, rec_end, jnp.inf)
     node_job = rm.prepopulate(system.n_nodes, table.first_node, table.nodes,
                               running0)
+    # the per-node end time carries each job's own ``end`` value (a
+    # gather, once; the prepopulation fill is not exact in f32)
+    node_end = jnp.where(node_job >= 0, end[jnp.maximum(node_job, 0)],
+                         jnp.inf)
     job_group_nodes = rm.prepopulate_groups(
         system.n_nodes, system.cooling.n_groups, table.first_node,
         table.nodes, running0)
@@ -112,8 +116,8 @@ def init_state(system: SystemConfig, table: T.JobTable, t0: float,
         t=jnp.float32(t0), step=jnp.int32(0), jstate=jstate, start=start,
         end=end, progress=progress,
         jenergy=jnp.zeros((J,), jnp.float32), node_job=node_job,
-        free_count=free_count, job_group_nodes=job_group_nodes,
-        accounts=accounts,
+        node_end=node_end, free_count=free_count,
+        job_group_nodes=job_group_nodes, accounts=accounts,
         cooling=cooling.init_state(system.cooling),
         energy_total=jnp.float32(0.0), energy_it=jnp.float32(0.0),
         energy_loss=jnp.float32(0.0), completed=jnp.float32(0.0),
@@ -127,12 +131,22 @@ def init_state(system: SystemConfig, table: T.JobTable, t0: float,
 # Engine phases.
 # ---------------------------------------------------------------------------
 def _prepare_and_arrivals(system: SystemConfig, table: T.JobTable,
-                          st: T.SimState) -> T.SimState:
-    """Phases (1)+(2): completions, node release, accounting, arrivals."""
+                          st: T.SimState, has_grid: bool) -> T.SimState:
+    """Phases (1)+(2): completions, node release, accounting, arrivals.
+
+    ``has_grid`` (static) picks the release: DVFS moves ``end`` after
+    placement on the grid path, which frees nodes by a gather of the
+    per-job flag; every other path compares ``t`` with ``node_end``.
+    ``obs.timing.RELEASE_STATS`` counts which one each trace took."""
     with jax.named_scope(phases.PREPARE):
         t = st.t
         done_now = (st.jstate == T.RUNNING) & (t >= st.end)
-        node_job = rm.release_done(st.node_job, done_now)
+        if has_grid:
+            obs_timing.RELEASE_STATS["job_gather"] += 1
+            node_job = rm.release_done_gather(st.node_job, done_now)
+        else:
+            obs_timing.RELEASE_STATS["node_end"] += 1
+            node_job = rm.release_done(st.node_job, st.node_end, t)
         freed = jnp.sum(jnp.where(done_now, table.nodes, 0))
         jstate = jnp.where(done_now, T.DONE, st.jstate)
         accounts = acct_mod.fold_completions(system, table, st.accounts,
@@ -299,7 +313,7 @@ def engine_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
     enables the stochastic failure + demand-response layer (repro.events);
     all three are compile-time ``None`` when absent (their machinery folds
     away and the graph is bit-identical to the pre-events engine)."""
-    st = _prepare_and_arrivals(system, table, st)
+    st = _prepare_and_arrivals(system, table, st, signals is not None)
     if events is not None:
         # phase (2b): draw failures/repairs, kill hit jobs, update the
         # availability map; DR cap steps are evaluated at the same point
@@ -376,7 +390,7 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
     setpoint_delta = 0.0 if scen is None else scen.setpoint_delta_c
     cells_offline = 0.0 if scen is None else scen.cells_offline
     cap_scale = 1.0 if scen is None else scen.cap_scale
-    st = _prepare_and_arrivals(system, table, st)
+    st = _prepare_and_arrivals(system, table, st, signals is not None)
     hall_aware = system.cooling.n_halls > 1
     with jax.named_scope(phases.QUEUE_ORDER):
         thermal = cooling.thermal_now(system.cooling, st.cooling,
@@ -391,7 +405,7 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
             group_pos = jnp.arange(system.cooling.n_groups, dtype=jnp.int32)
 
     def body(i, carry):
-        node_job, jstate, start, end, free_count, free_ok = carry
+        node_job, node_end, jstate, start, end, free_count, free_ok = carry
         j = place_ids[i]
         ok = j >= 0
         jj = jnp.maximum(j, 0)
@@ -402,7 +416,9 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
             sel = rm.firstfree_mask_ordered(node_job, need, order_nodes)
         else:
             sel = rm.firstfree_mask(node_job, need)
-        node_job = rm.place(node_job, sel, jj, can)
+        end_j = st.t + table.wall[jj]
+        node_job, node_end = rm.place(node_job, node_end, sel, jj, end_j,
+                                      can)
         free_count = free_count - jnp.where(can, need, 0)
         if hall_aware:
             free_ok = free_ok - jnp.where(
@@ -410,19 +426,20 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
         # (inert carry on a flat plant — the global gate never reads it)
         jstate = jstate.at[jj].set(jnp.where(can, T.RUNNING, jstate[jj]))
         start = start.at[jj].set(jnp.where(can, st.t, start[jj]))
-        end = end.at[jj].set(jnp.where(can, st.t + table.wall[jj], end[jj]))
-        return node_job, jstate, start, end, free_count, free_ok
+        end = end.at[jj].set(jnp.where(can, end_j, end[jj]))
+        return node_job, node_end, jstate, start, end, free_count, free_ok
 
     with jax.named_scope(phases.ADMISSION):
-        carry = (st.node_job, st.jstate, st.start, st.end, st.free_count,
-                 jnp.int32(free_ok0))
-        node_job, jstate, start, end, free_count, _ = jax.lax.fori_loop(
-            0, place_ids.shape[0], body, carry)
+        carry = (st.node_job, st.node_end, st.jstate, st.start, st.end,
+                 st.free_count, jnp.int32(free_ok0))
+        (node_job, node_end, jstate, start, end, free_count,
+         _) = jax.lax.fori_loop(0, place_ids.shape[0], body, carry)
         job_group_nodes = rm.record_placements(
             st.job_group_nodes, st.node_job, group_pos, place_ids, st.jstate,
             jstate, table.nodes)
     st = dataclasses.replace(st, jstate=jstate, start=start, end=end,
-                             node_job=node_job, free_count=free_count,
+                             node_job=node_job, node_end=node_end,
+                             free_count=free_count,
                              job_group_nodes=job_group_nodes)
     return _tick(system, table, st, grid,
                  None if grid is None else grid.cap_w * cap_scale, wx,

@@ -24,6 +24,14 @@ it: only RUNNING jobs' columns are read, and a job leaves RUNNING exactly
 when its nodes are freed. Per-CDU power and heat come from it as J x G
 dense work (``repro.power.model.group_power``) instead of a gather of
 per-job power onto every node.
+
+Beside the node map rides ``node_end[N]``, the end time of the job on
+each node, which ``place`` writes with the very f32 value the scheduler
+writes into the job's ``end``. Release is then an elementwise compare
+(``release_done``), not a gather of the per-job completion flag onto
+every node. On the grid path DVFS stretches ``end`` after placement, so
+``node_end`` is written there but not read: that path releases through
+``release_done_gather``.
 """
 from __future__ import annotations
 
@@ -35,8 +43,21 @@ from repro.core import types as T
 from repro.kernels.power_topo.ref import group_sizes, segment_dot
 
 
-def release_done(node_job: jnp.ndarray, done_now: jnp.ndarray) -> jnp.ndarray:
-    """Free every node whose occupying job just completed."""
+def release_done(node_job: jnp.ndarray, node_end: jnp.ndarray,
+                 t: jnp.ndarray) -> jnp.ndarray:
+    """Free every node whose occupying job has reached its end time: an
+    elementwise compare against the per-node end time ``place`` wrote.
+    Exact while every running job's ``end`` is the one written at
+    placement (every path but the grid's)."""
+    return jnp.where((node_job >= 0) & (t >= node_end), -1, node_job)
+
+
+def release_done_gather(node_job: jnp.ndarray,
+                        done_now: jnp.ndarray) -> jnp.ndarray:
+    """Free every node whose occupying job just completed, by a gather of
+    the per-job flag onto the nodes. The grid path's release: DVFS
+    stretches a running job's ``end`` there, so ``node_end`` falls behind
+    it."""
     occupied = node_job >= 0
     safe = jnp.maximum(node_job, 0)
     freed = occupied & jnp.take(done_now, safe)
@@ -77,10 +98,13 @@ def contiguous_mask(n_nodes: int, first: jnp.ndarray,
     return (idx >= first) & (idx < first + need)
 
 
-def place(node_job: jnp.ndarray, sel: jnp.ndarray, jid: jnp.ndarray,
-          do_place: jnp.ndarray) -> jnp.ndarray:
-    """Assign job ``jid`` to nodes in ``sel`` when ``do_place``."""
-    return jnp.where(sel & do_place, jid, node_job)
+def place(node_job: jnp.ndarray, node_end: jnp.ndarray, sel: jnp.ndarray,
+          jid: jnp.ndarray, end_t: jnp.ndarray, do_place: jnp.ndarray
+          ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Assign job ``jid``, ending at ``end_t``, to nodes in ``sel`` when
+    ``do_place``: (node_job, node_end)."""
+    m = sel & do_place
+    return jnp.where(m, jid, node_job), jnp.where(m, end_t, node_end)
 
 
 def prepopulate(n_nodes: int, first_node: jnp.ndarray, nodes: jnp.ndarray,

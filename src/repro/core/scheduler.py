@@ -325,7 +325,7 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
     t = st.t
 
     def body(i, carry):
-        (node_job, jstate, start, end, free_count, free_ok, proj,
+        (node_job, node_end, jstate, start, end, free_count, free_ok, proj,
          blocked_any, head_blocked, head_capped,
          shadow_t, shadow_extra) = carry
         j = order[i]
@@ -398,7 +398,9 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
                                          can_bf & cap_ok & th_ok)
 
         # --- commit ---
-        node_job = rm.place(node_job, sel, j, place)
+        end_j = t + table.wall[j]
+        node_job, node_end = rm.place(node_job, node_end, sel, j, end_j,
+                                      place)
         free_count = free_count - jnp.where(place, need, 0)
         if hall_aware:
             free_ok = free_ok - jnp.where(
@@ -409,27 +411,28 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
             proj = proj + jnp.where(place, est_add_pw[j], 0.0)
         jstate = jstate.at[j].set(jnp.where(place, T.RUNNING, jstate[j]))
         start = start.at[j].set(jnp.where(place, t, start[j]))
-        end = end.at[j].set(jnp.where(place, t + table.wall[j], end[j]))
+        end = end.at[j].set(jnp.where(place, end_j, end[j]))
 
         blocked_any |= valid & (~fits | ~cap_ok | ~th_ok)
         head_blocked |= valid & ~fits
         head_capped |= valid & fits & (~cap_ok | ~th_ok)
-        return (node_job, jstate, start, end, free_count, free_ok, proj,
-                blocked_any, head_blocked, head_capped,
+        return (node_job, node_end, jstate, start, end, free_count, free_ok,
+                proj, blocked_any, head_blocked, head_capped,
                 shadow_t, shadow_extra)
 
     with jax.named_scope(phases.ADMISSION):
-        carry = (st.node_job, st.jstate, st.start, st.end, st.free_count,
-                 jnp.int32(free_ok0),
+        carry = (st.node_job, st.node_end, st.jstate, st.start, st.end,
+                 st.free_count, jnp.int32(free_ok0),
                  jnp.float32(proj_pw), jnp.bool_(False), jnp.bool_(False),
                  jnp.bool_(False), jnp.float32(jnp.inf), jnp.int32(0))
         K = min(system.sched_budget, table.num_jobs)
-        (node_job, jstate, start, end, free_count,
+        (node_job, node_end, jstate, start, end, free_count,
          *_rest) = jax.lax.fori_loop(0, K, body, carry)
         job_group_nodes = rm.record_placements(
             st.job_group_nodes, st.node_job, group_pos, order[:K], st.jstate,
             jstate, table.nodes)
 
     return dataclasses.replace(st, jstate=jstate, start=start, end=end,
-                               node_job=node_job, free_count=free_count,
+                               node_job=node_job, node_end=node_end,
+                               free_count=free_count,
                                job_group_nodes=job_group_nodes)

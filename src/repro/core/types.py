@@ -215,6 +215,12 @@ class SimState:
                             # == wall-clock elapsed when never throttled)
     jenergy: jnp.ndarray    # f32[J] accumulated job energy (J)
     node_job: jnp.ndarray   # i32[N] job id occupying each node, -1 when free
+    # f32[N] end time of the job occupying each node, written where a job
+    # gains nodes with the same f32 value as its ``end`` (never read on a
+    # free or down node), so release is an elementwise ``t >= node_end``.
+    # On the grid path DVFS stretches ``end`` after placement and this is
+    # only a lower bound: that path writes it but releases by a gather.
+    node_end: jnp.ndarray
     free_count: jnp.ndarray  # i32[] number of free nodes
     # i32[G, J] nodes each job holds in each CDU group (the node map's
     # per-group summary, written where a job is placed and never cleared:

@@ -207,6 +207,11 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # Process totals: executables built or loaded from the persistent cache,
 # and the seconds spent building them.
 COMPILE_STATS = {"compiles": 0, "compile_s": 0.0, "lower_s": 0.0}
+# Traces of the engine's node release, by kind (``engine
+# ._prepare_and_arrivals``): the per-node end-time compare, or the grid
+# path's gather of the per-job flag. Counted at trace time, so each
+# compiled runner adds one; nothing runs on the device.
+RELEASE_STATS = {"node_end": 0, "job_gather": 0}
 _stats_lock = threading.Lock()
 
 

@@ -252,8 +252,9 @@ def test_decode_rejects_wrong_shape_and_version(template):
     mangled["leaves"]["t"]["shape"] = [3]
     with pytest.raises(snap.SnapshotError):
         snap.decode_carry(mangled, template)
-    # the node map and its per-group summary are both part of the carry
-    for leaf in ("node_job", "job_group_nodes"):
+    # the node map, its per-node end times and its per-group summary are
+    # all part of the carry
+    for leaf in ("node_job", "node_end", "job_group_nodes"):
         dropped = json.loads(json.dumps(good))
         del dropped["leaves"][leaf]
         with pytest.raises(snap.SnapshotError, match=leaf):

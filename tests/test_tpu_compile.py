@@ -29,6 +29,7 @@ from repro.cooling import model as cool
 from repro.datasets import loaders
 from repro.kernels.power_topo import ops
 from repro.obs import phases
+from repro.obs import timing
 from repro.parallel import sharding as psh
 from repro.systems.config import get_system
 
@@ -80,9 +81,13 @@ def test_frontier_scan_compiles_for_one_chip(topo, frontier):
     system, table, st0 = frontier
     one = SingleDeviceSharding(topo.devices[0])
     n_steps = int(DAY_S / system.dt)
+    traced = dict(timing.RELEASE_STATS)
     compiled = eng._segment_fn(system, n_steps).lower(
         _shapes(table, one), _shapes(st0, one),
         _shapes(T.Scenario.make("fcfs", "easy"), one), None, None).compile()
+    # without grid signals the runner releases nodes by their end time
+    assert timing.RELEASE_STATS["node_end"] > traced["node_end"]
+    assert timing.RELEASE_STATS["job_gather"] == traced["job_gather"]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 16 * 2**30
     assert n_steps == 5760
@@ -98,15 +103,19 @@ def test_frontier_scan_compiles_for_one_chip(topo, frontier):
     # spreads a per-job value over the nodes there
     assert not node_wide_gathers(compiled.as_text(), system.n_nodes,
                                  {phases.POWER, phases.COOLING})
+    # release compares each node's end time with the clock: no per-job
+    # flag (pred) or value is gathered onto the nodes there either
+    assert not node_wide_gathers(compiled.as_text(), system.n_nodes,
+                                 {phases.PREPARE})
 
 
-_GATHER = re.compile(r"=\s*f32\[([\d,]*)\]\S*\s+gather\(")
+_GATHER = re.compile(r"=\s*[a-z]\w*\[([\d,]*)\]\S*\s+gather\(")
 
 
 def node_wide_gathers(text, n_nodes, phase_set):
-    """Gathers (fused or not) named in one of ``phase_set`` whose f32
-    output has a multiple of ``n_nodes`` elements: per-node values, or
-    S rows of them under a vmap."""
+    """Gathers (fused or not) named in one of ``phase_set`` whose
+    output, of any element type, has a multiple of ``n_nodes`` elements:
+    per-node values, or S rows of them under a vmap."""
     found = []
     for line in text.splitlines():
         m = _GATHER.search(line)
