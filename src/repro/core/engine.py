@@ -41,6 +41,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.cooling import model as cooling
 from repro.cooling import weather as wsig
@@ -447,17 +448,16 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
 
 
 # ---------------------------------------------------------------------------
-# Full simulation.
+# Full simulation: one cached scan runner behind every entry.
 # ---------------------------------------------------------------------------
-# Buffer donation on the scan-carry runners: the input carry and the
-# output carry are the same SimState pytree, so XLA can write the scan
-# in place instead of allocating a second full copy of the (node map +
-# job lifecycle + ledgers) state per call. Donated *inputs* are
-# consumed — every runner below either builds its carry fresh
-# (init_state / jnp.stack) or its callers treat the passed carry as
-# moved-from (repro.serve reassigns; see docs/serving.md). Only the
-# carry argument is donated: tables/signals are broadcast inputs reused
-# across calls, and the sweep runners' broadcast st0 cannot alias their
+# Buffer donation on the scan carry: the input carry and the output carry
+# are the same SimState pytree, so XLA can write the scan in place instead
+# of allocating a second full copy of the (node map + job lifecycle +
+# ledgers) state per call. A donated input is consumed: every entry either
+# builds its carry fresh (init_state / jnp.stack) or its callers treat the
+# passed carry as moved-from (repro.serve reassigns; see docs/serving.md).
+# Only a per-row carry is donated: tables/signals are broadcast inputs
+# reused across calls, and a sweep's broadcast st0 cannot alias its
 # batched output. REPRO_NO_DONATE=1 disables donation for debugging
 # (e.g. to inspect a carry after a call that consumed it).
 DONATE_CARRIES = not os.environ.get("REPRO_NO_DONATE")
@@ -467,43 +467,39 @@ def _donate(*argnums: int) -> tuple:
     return tuple(argnums) if DONATE_CARRIES else ()
 
 
-# Jitted-runner caches, keyed on (kind, system, n_steps, ...): one for the
-# sweep, sharded-sweep and segment runners (``simulate`` runs the segment
-# runner from a fresh carry), one for the static fast path's runners
-# (policy and backfill compiled in). Both are LRU-bounded: a long-lived
-# server (repro.serve) advancing many distinct segment lengths would
-# otherwise grow them without limit — least-recently-used entries are
-# evicted past ``SWEEP_CACHE_LIMIT`` (dropping a compiled runner is
-# safe: the next same-shape call re-jits and re-enters the cache).
+# The compiled-runner cache, keyed on (system, n_steps, events, axes, mesh,
+# static, sizes) — every argument of ``_runner``. It is LRU-bounded: a
+# long-lived server (repro.serve) advancing many distinct segment lengths
+# would otherwise grow it without limit — least-recently-used runners are
+# evicted past ``SWEEP_CACHE_LIMIT`` (dropping a compiled runner is safe:
+# the next same-shape call re-jits and re-enters the cache).
 _SWEEP_CACHE: "collections.OrderedDict" = collections.OrderedDict()
-_STATIC_CACHE: dict = {}
+# ``bench/tests`` clears the cache under this older name as well.
+_STATIC_CACHE = _SWEEP_CACHE
 SWEEP_CACHE_LIMIT = 32
-# Monotonic hit/miss/eviction counters over both runner caches, always
-# on. A steady-state training loop should show hits only after
-# generation 0; ``ml.train`` snapshots the deltas per generation and the
-# run manifest embeds the totals.
+# Monotonic hit/miss/eviction counters of the runner cache, always on. A
+# steady-state training loop should show hits only after generation 0;
+# ``ml.train`` snapshots the deltas per generation and the run manifest
+# embeds the totals.
 SWEEP_CACHE_STATS = {"hits": 0, "misses": 0, "evictions": 0}
 
 
-def _cache_lookup(key, cache=None):
-    """LRU lookup in a runner cache (``_SWEEP_CACHE`` by default); bumps
-    the hit/miss counters."""
-    cache = _SWEEP_CACHE if cache is None else cache
-    fn = cache.pop(key, None)
+def _cache_lookup(key):
+    """LRU lookup in the runner cache; bumps the hit/miss counters."""
+    fn = _SWEEP_CACHE.pop(key, None)
     if fn is not None:
-        cache[key] = fn                     # now the most recently used
+        _SWEEP_CACHE[key] = fn              # now the most recently used
     SWEEP_CACHE_STATS["hits" if fn is not None else "misses"] += 1
     return fn
 
 
-def _cache_store(key, fn, cache=None):
+def _cache_store(key, fn):
     """Insert a runner, evicting least-recently-used entries past the
     bound (counted in ``SWEEP_CACHE_STATS["evictions"]``)."""
-    cache = _SWEEP_CACHE if cache is None else cache
-    cache.pop(key, None)
-    cache[key] = fn
-    while len(cache) > SWEEP_CACHE_LIMIT:
-        del cache[next(iter(cache))]
+    _SWEEP_CACHE.pop(key, None)
+    _SWEEP_CACHE[key] = fn
+    while len(_SWEEP_CACHE) > SWEEP_CACHE_LIMIT:
+        del _SWEEP_CACHE[next(iter(_SWEEP_CACHE))]
         SWEEP_CACHE_STATS["evictions"] += 1
     return fn
 
@@ -555,23 +551,108 @@ def _scan(n_steps: int, carry, step):
                             length=int(n_steps))
 
 
-def _segment_fn(system: SystemConfig, n_steps: int, events=None):
-    """Cached jitted scan of ``n_steps`` engine steps from a given carry:
-    (table, carry, scen, signals, weather) -> (carry, history). The carry
-    is donated."""
-    key = ("segment", system, int(n_steps), events)
+def sweep_mesh(devices=None) -> jax.sharding.Mesh:
+    """1-D ``("scenario",)`` mesh over ``devices`` (default: every local
+    device): the what-if sweep axis of ``simulate_sweep_sharded``.
+    Scenario rows are embarrassingly parallel (they share the job table
+    and signal arrays by replication), so a flat mesh is always the right
+    shape."""
+    devs = jax.devices() if devices is None else list(devices)
+    return jax.sharding.Mesh(np.array(devs), ("scenario",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+
+
+def scenario_spec() -> jax.sharding.PartitionSpec:
+    """PartitionSpec sharding dim0 over the sweep mesh's scenario axis."""
+    return jax.sharding.PartitionSpec("scenario")
+
+
+def pad_leading_axis(tree, multiple: int):
+    """Pad every leaf's leading axis up to a multiple of ``multiple`` by
+    replicating the last row (scenario batches must divide the mesh; the
+    padded rows are dropped by the caller). Returns (padded_tree, pad)."""
+    sizes = {leaf.shape[0] for leaf in jax.tree_util.tree_leaves(tree)}
+    if len(sizes) != 1:
+        raise ValueError(f"inconsistent leading-axis sizes: {sorted(sizes)}")
+    (size,) = sizes
+    pad = (-size) % multiple
+
+    def one(x):
+        if pad == 0:
+            return x
+        rep = jnp.broadcast_to(x[-1:], (pad,) + tuple(x.shape[1:]))
+        return jnp.concatenate([x, rep], axis=0)
+    return jax.tree_util.tree_map(one, tree), pad
+
+
+def _runner(system: SystemConfig, n_steps: int, events=None, *, axes=None,
+            mesh=None, static=None, sizes=()):
+    """The cached jitted scan of ``n_steps`` engine steps that every entry
+    runs: (table, carry, scenario, signals, weather) -> (carry, history).
+
+    ``jax.jit`` caches traces per *function identity*; building the runner
+    here, once per key, means repeated same-shape calls — the ES training
+    loop's per-generation rollouts (repro.ml.train), a server's segments
+    (repro.serve) — compile once and then run at steady-state throughput.
+
+    Args:
+      axes: None for a one-row program. Otherwise the vmap's
+        ``(carry_axis, weather_axis)``, each 0 (one per row) or None
+        (shared by broadcast); the scenario is always batched on axis 0,
+        the table and grid signals are always shared.
+      mesh: a 1-D ``("scenario",)`` mesh (``sweep_mesh``) to shard the
+        rows over, one ``shard_map`` around the vmap; the batch must
+        divide its size. Exposed so the program can be lowered for a mesh
+        of devices that are described rather than attached.
+      static: compile-time ``(policy, backfill)`` ids closed over in place
+        of the scenario operand (pass None for it), so only the selected
+        priority key is computed and every policy select folds away.
+      sizes: further key parts, for a caller whose operand sizes should
+        each get a runner of their own (``simulate_static``'s table and
+        ledger sizes: ``last_runner_hlo`` then lowers the one it ran).
+
+    The carry is donated exactly when it is per-row: one-row programs and
+    a batch of carries, never a sweep's broadcast ``st0``.
+    """
+    key = (system, int(n_steps), events, axes, mesh, static, sizes)
     fn = _cache_lookup(key)
-    if fn is None:
-        # signals/weather=None are empty pytrees and events=None is static
-        # (closed over): the no-grid / no-weather / no-failure fast paths
-        # in engine_step are selected at trace time and their machinery
-        # vanishes entirely
-        @functools.partial(jax.jit, donate_argnums=_donate(1))
-        def fn(table_, carry_, scen_, signals_, weather_):
-            return _scan(n_steps, carry_, lambda st: engine_step(
-                system, table_, st, scen_, signals_, weather_, events))
-        _cache_store(key, fn)
-    return fn
+    if fn is not None:
+        return fn
+    if static is not None:
+        # keyword construction with raw Python ints (-> static in the
+        # closure): every knob past policy/backfill takes its declared
+        # neutral default, so growing Scenario can never silently shift
+        # knobs
+        fixed = T.Scenario(policy=static[0], backfill=static[1])
+
+    # signals/weather=None are empty pytrees and events=None is static
+    # (closed over): the no-grid / no-weather / no-failure fast paths in
+    # engine_step are selected at trace time and their machinery vanishes
+    # entirely
+    def run(table_, carry_, scen_, signals_, weather_):
+        scen1 = fixed if static is not None else scen_
+        return _scan(n_steps, carry_, lambda st: engine_step(
+            system, table_, st, scen1, signals_, weather_, events))
+
+    per_row = axes is None or axes[0] == 0
+    if axes is not None:
+        carry_axis, w_axis = axes
+        run = jax.vmap(run, in_axes=(None, carry_axis, 0, None, w_axis))
+    if mesh is not None:
+        rows, rep = scenario_spec(), jax.sharding.PartitionSpec()
+        spec = lambda axis: rows if axis == 0 else rep
+        # check_vma=False: every loop carry inside engine_step (the scan's
+        # replicated initial state, the admission fori_loop's fresh
+        # constants) becomes per-device data after one step, which the
+        # varying-axes type check rejects. The body has no collective
+        # whose result depends on those types: scenario rows never
+        # communicate.
+        run = jax.shard_map(
+            run, mesh=mesh,
+            in_specs=(rep, spec(carry_axis), rows, rep, spec(w_axis)),
+            out_specs=rows, check_vma=False)
+    return _cache_store(key, jax.jit(
+        run, donate_argnums=_donate(1) if per_row else ()))
 
 
 def simulate(system: SystemConfig, table: T.JobTable, scen: T.Scenario,
@@ -617,7 +698,7 @@ def simulate(system: SystemConfig, table: T.JobTable, scen: T.Scenario,
                 carry = init_state(system, table, t0, t1, accounts,
                                    num_accounts, events)
         with obs_timing.span("engine.runner"):
-            fn = _segment_fn(system, n_steps, events)
+            fn = _runner(system, n_steps, events)
         return _launch(fn, table, carry, scen, signals, weather)
 
 
@@ -637,52 +718,17 @@ def simulate_static(system: SystemConfig, table: T.JobTable, policy: str,
     instead of ``init_state`` (see ``simulate``)."""
     with obs_timing.span("engine.dispatch", entry="simulate_static"):
         n_steps = int(round((t1 - t0) / system.dt))
-        # keyword/default construction with raw Python values (-> static
-        # in the closure): every knob past policy/backfill takes its
-        # declared neutral default, so growing Scenario can never silently
-        # shift knobs
         with obs_timing.span("engine.scenarios"):
-            scen = T.Scenario(policy=T.POLICY_NAMES[policy],
-                              backfill=T.BACKFILL_NAMES[backfill])
+            static = (T.POLICY_NAMES[policy], T.BACKFILL_NAMES[backfill])
         if carry is None:
             with obs_timing.span("engine.init_state"):
                 carry = init_state(system, table, t0, t1, accounts,
                                    num_accounts)
         with obs_timing.span("engine.runner"):
-            key = ("static", system, policy, backfill, n_steps,
-                   table.num_jobs, table.prof_len, num_accounts,
-                   signals is None, weather is None)
-            fn = _cache_lookup(key, _STATIC_CACHE)
-            if fn is None:
-                def run(table_, st0_, signals_, weather_):
-                    return _scan(n_steps, st0_, lambda st: engine_step(
-                        system, table_, st, scen, signals_, weather_))
-                fn = _cache_store(key, jax.jit(run,
-                                               donate_argnums=_donate(1)),
-                                  _STATIC_CACHE)
-        return _launch(fn, table, carry, signals, weather)
-
-
-def _sweep_fn(system: SystemConfig, n_steps: int, w_axis, events=None):
-    """Cached jitted sweep runner keyed on (system, horizon, weather axis,
-    event layer).
-
-    ``jax.jit`` caches traces per *function identity*; defining the runner
-    inside ``simulate_sweep`` would re-jit on every call. Caching it here
-    means repeated same-shape sweeps — notably the per-generation rollouts
-    of the ES training loop (repro.ml.train) — compile once and then run
-    at steady-state throughput."""
-    key = (system, n_steps, w_axis, events)
-    fn = _cache_lookup(key)
-    if fn is None:
-        @jax.jit
-        def fn(table_, st0_, scen_, signals_, weather_):
-            def one(scen1, weather1):
-                return _scan(n_steps, st0_, lambda st: engine_step(
-                    system, table_, st, scen1, signals_, weather1, events))
-            return jax.vmap(one, in_axes=(0, w_axis))(scen_, weather_)
-        _cache_store(key, fn)
-    return fn
+            fn = _runner(system, n_steps, static=static, sizes=(
+                table.num_jobs, table.prof_len, num_accounts,
+                signals is None, weather is None))
+        return _launch(fn, table, carry, None, signals, weather)
 
 
 def _stack_weather(weather, scens):
@@ -730,46 +776,8 @@ def simulate_sweep(system: SystemConfig, table: T.JobTable,
         batched = T.stack_scenarios(scens)
         weather_b, w_axis = _stack_weather(weather, scens)
         with obs_timing.span("engine.runner"):
-            run = _sweep_fn(system, n_steps, w_axis, events)
-        return _launch(run, table, st0, batched, signals, weather_b)
-
-
-def sharded_sweep_fn(system: SystemConfig, n_steps: int, w_axis, events,
-                     mesh):
-    """Cached jitted runner of ``simulate_sweep_sharded`` on ``mesh`` (a
-    1-D ``("scenario",)`` mesh, ``repro.parallel.sharding.sweep_mesh``).
-
-    Takes (table, st0, stacked scenarios, signals, weather); the scenario
-    batch (and a stacked weather batch, ``w_axis == 0``) must divide the
-    mesh size. Exposed so the program can be lowered for a mesh of
-    devices that are described rather than attached."""
-    from repro.parallel import sharding as psh
-
-    # compiled-program cache, same rationale as _sweep_fn: per-generation
-    # training rollouts re-enter here with identical shapes
-    key = ("sharded", system, n_steps, w_axis, mesh, events)
-    run = _cache_lookup(key)
-    if run is not None:
-        return run
-    scen_spec = psh.scenario_spec()
-    rep = jax.sharding.PartitionSpec()
-    w_spec = scen_spec if w_axis == 0 else rep
-
-    def shard(table_s, st0_s, scen_s, signals_s, weather_s):
-        def one(scen1, weather1):
-            return _scan(n_steps, st0_s, lambda st: engine_step(
-                system, table_s, st, scen1, signals_s, weather1, events))
-        return jax.vmap(one, in_axes=(0, w_axis))(scen_s, weather_s)
-
-    # check_vma=False: every loop carry inside engine_step (the scan's
-    # replicated initial state, the admission fori_loop's fresh constants)
-    # becomes per-device data after one step, which the varying-axes type
-    # check rejects. The body has no collective whose result depends on
-    # those types: scenario rows never communicate.
-    run = jax.jit(jax.shard_map(
-        shard, mesh=mesh, in_specs=(rep, rep, scen_spec, rep, w_spec),
-        out_specs=scen_spec, check_vma=False))
-    return _cache_store(key, run)
+            fn = _runner(system, n_steps, events, axes=(None, w_axis))
+        return _launch(fn, table, st0, batched, signals, weather_b)
 
 
 def simulate_sweep_sharded(system: SystemConfig, table: T.JobTable,
@@ -783,20 +791,18 @@ def simulate_sweep_sharded(system: SystemConfig, table: T.JobTable,
     """``simulate_sweep`` with the scenario axis sharded across devices.
 
     One ``jax.shard_map`` over a 1-D ``("scenario",)`` mesh
-    (``repro.parallel.sharding.sweep_mesh()`` over every local device;
-    ``sharded_sweep_fn`` takes any other): each device scans its slice of
-    the scenario batch with the job table, initial state and grid signals
-    replicated — scenario rows never communicate, so the program contains
-    no collectives and scales linearly across hosts. Per-scenario weather
+    (``sweep_mesh()`` over every local device; ``_runner(..., mesh=)``
+    takes any other): each device scans its slice of the scenario batch
+    with the job table, initial state and grid signals replicated —
+    scenario rows never communicate, so the program contains no
+    collectives and scales linearly across hosts. Per-scenario weather
     (a list, possibly hall-stacked — see ``cooling.weather.stack_halls``)
     is sharded with the scenarios. The batch is padded to the device
     count by replicating the last scenario; padded rows are sliced off
     the result. With a single device this degenerates to exactly
     ``simulate_sweep`` (one vmapped program, no sharding machinery).
     """
-    from repro.parallel import sharding as psh
-
-    mesh = psh.sweep_mesh()
+    mesh = sweep_mesh()
     n_dev = mesh.devices.size
     if n_dev <= 1:
         return simulate_sweep(system, table, scens, t0, t1, accounts,
@@ -808,12 +814,13 @@ def simulate_sweep_sharded(system: SystemConfig, table: T.JobTable,
                              events)
         weather_b, w_axis = _stack_weather(weather, scens)
         S = len(scens)
-        batched, _ = psh.pad_leading_axis(T.stack_scenarios(scens), n_dev)
+        batched, _ = pad_leading_axis(T.stack_scenarios(scens), n_dev)
         if w_axis == 0:
-            weather_b, _ = psh.pad_leading_axis(weather_b, n_dev)
+            weather_b, _ = pad_leading_axis(weather_b, n_dev)
         with obs_timing.span("engine.runner"):
-            run = sharded_sweep_fn(system, n_steps, w_axis, events, mesh)
-        final, hist = _launch(run, table, st0, batched, signals, weather_b)
+            fn = _runner(system, n_steps, events, axes=(None, w_axis),
+                         mesh=mesh)
+        final, hist = _launch(fn, table, st0, batched, signals, weather_b)
         trim = lambda x: x[:S]
         return (jax.tree_util.tree_map(trim, final),
                 jax.tree_util.tree_map(trim, hist))
@@ -859,7 +866,7 @@ def simulate_segment(system: SystemConfig, table: T.JobTable,
     """
     with obs_timing.span("engine.dispatch", entry="simulate_segment"):
         with obs_timing.span("engine.runner"):
-            fn = _segment_fn(system, n_steps, events)
+            fn = _runner(system, n_steps, events)
         return _launch(fn, table, carry, scen, signals, weather)
 
 
@@ -877,7 +884,8 @@ def simulate_segment_sweep(system: SystemConfig, table: T.JobTable,
     together: one compiled program per (system, segment length), B
     lock-stepped scans inside. This is what lets a serving session
     coalesce concurrent client what-ifs into a single dispatch
-    (repro.serve.session).
+    (repro.serve.session). The stacked carries are a fresh buffer every
+    call, so donating them is always safe.
 
     Args:
       carries: list of ``SimState`` carries (stacked on axis 0), one per
@@ -896,18 +904,5 @@ def simulate_segment_sweep(system: SystemConfig, table: T.JobTable,
                                              *carries)
         batched = T.stack_scenarios(list(scens))
         with obs_timing.span("engine.runner"):
-            key = ("segment_sweep", system, int(n_steps), events)
-            fn = _cache_lookup(key)
-            if fn is None:
-                # the stacked carries are a fresh jnp.stack buffer every
-                # call, so donating them is always safe and saves the
-                # B-branch copy
-                @functools.partial(jax.jit, donate_argnums=_donate(1))
-                def fn(table_, carries_, scen_, signals_, weather_):
-                    def one(carry1, scen1):
-                        return _scan(n_steps, carry1, lambda st: engine_step(
-                            system, table_, st, scen1, signals_, weather_,
-                            events))
-                    return jax.vmap(one, in_axes=(0, 0))(carries_, scen_)
-                _cache_store(key, fn)
+            fn = _runner(system, n_steps, events, axes=(0, None))
         return _launch(fn, table, stacked, batched, signals, weather)

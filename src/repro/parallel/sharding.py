@@ -31,41 +31,6 @@ from repro.models.common import ArchConfig, Annotated
 Rules = Dict[str, Any]
 
 
-# -- scenario-axis sharding (digital-twin sweeps) -----------------------------
-def sweep_mesh(devices=None) -> Mesh:
-    """1-D ``("scenario",)`` mesh over ``devices`` (default: every local
-    device): the what-if sweep axis of ``engine.simulate_sweep_sharded``.
-    Scenario rows are embarrassingly parallel (they share the job table
-    and signal arrays by replication), so a flat mesh is always the right
-    shape."""
-    devs = jax.devices() if devices is None else list(devices)
-    return Mesh(np.array(devs), ("scenario",),
-                axis_types=(jax.sharding.AxisType.Auto,))
-
-
-def pad_leading_axis(tree, multiple: int):
-    """Pad every leaf's leading axis up to a multiple of ``multiple`` by
-    replicating the last row (scenario batches must divide the mesh; the
-    padded rows are dropped by the caller). Returns (padded_tree, pad)."""
-    sizes = {leaf.shape[0] for leaf in jax.tree_util.tree_leaves(tree)}
-    if len(sizes) != 1:
-        raise ValueError(f"inconsistent leading-axis sizes: {sorted(sizes)}")
-    (size,) = sizes
-    pad = (-size) % multiple
-
-    def one(x):
-        if pad == 0:
-            return x
-        rep = jax.numpy.broadcast_to(x[-1:], (pad,) + tuple(x.shape[1:]))
-        return jax.numpy.concatenate([x, rep], axis=0)
-    return jax.tree_util.tree_map(one, tree), pad
-
-
-def scenario_spec() -> Any:
-    """PartitionSpec sharding dim0 over the sweep mesh's scenario axis."""
-    return P("scenario")
-
-
 DEFAULT_RULES: Rules = {
     "batch": ("pod", "data"),
     "embed": "data",          # FSDP

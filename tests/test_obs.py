@@ -224,21 +224,6 @@ def test_static_path_counters_and_parity(small_system, small_table):
     assert "engine.compile" not in summary["spans"]
 
 
-def test_static_cache_is_bounded_and_counts_evictions(monkeypatch):
-    monkeypatch.setattr(eng, "_STATIC_CACHE", {})
-    monkeypatch.setattr(eng, "SWEEP_CACHE_LIMIT", 2)
-    monkeypatch.setattr(eng, "SWEEP_CACHE_STATS",
-                        {"hits": 0, "misses": 0, "evictions": 0})
-    for i in range(4):
-        assert eng._cache_lookup(i, eng._STATIC_CACHE) is None
-        eng._cache_store(i, f"runner{i}", eng._STATIC_CACHE)
-    assert eng._cache_lookup(2, eng._STATIC_CACHE) == "runner2"
-    eng._cache_store(4, "runner4", eng._STATIC_CACHE)
-    assert list(eng._STATIC_CACHE) == [2, 4]
-    assert eng.SWEEP_CACHE_STATS == {"hits": 1, "misses": 4,
-                                     "evictions": 3}
-
-
 def test_sweep_cache_stats_monotonic(small_system, small_table):
     before = dict(eng.SWEEP_CACHE_STATS)
     scens = [T.Scenario.make("fcfs"), T.Scenario.make("sjf")]

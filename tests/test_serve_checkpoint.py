@@ -188,30 +188,72 @@ def test_rejects_partial_interval_horizon(topo_case):
 # ---------------------------------------------------------------------------
 # Satellite: the runner cache must stay bounded under a long-lived server.
 # ---------------------------------------------------------------------------
-def test_sweep_cache_lru_bound(monkeypatch):
-    """Regression: ``_SWEEP_CACHE`` evicts least-recently-used runners
-    past ``SWEEP_CACHE_LIMIT`` (counted), instead of growing forever."""
+@pytest.mark.parametrize("limit, n_keys", [(4, 10), (2, 4)])
+def test_sweep_cache_lru_bound(monkeypatch, limit, n_keys):
+    """Regression: the one runner cache (``_SWEEP_CACHE``) evicts
+    least-recently-used runners past ``SWEEP_CACHE_LIMIT`` (counted),
+    instead of growing forever."""
     import collections
     monkeypatch.setattr(eng, "_SWEEP_CACHE", collections.OrderedDict())
-    monkeypatch.setattr(eng, "SWEEP_CACHE_LIMIT", 4)
+    monkeypatch.setattr(eng, "SWEEP_CACHE_LIMIT", limit)
     monkeypatch.setattr(eng, "SWEEP_CACHE_STATS",
                         {"hits": 0, "misses": 0, "evictions": 0})
 
-    for i in range(10):
+    for i in range(n_keys):
         assert eng._cache_lookup(("k", i)) is None
         eng._cache_store(("k", i), f"runner{i}")
-    assert len(eng._SWEEP_CACHE) == 4
-    assert eng.SWEEP_CACHE_STATS["evictions"] == 6
-    assert eng.SWEEP_CACHE_STATS["misses"] == 10
     # survivors are the most recently stored
-    assert list(eng._SWEEP_CACHE) == [("k", i) for i in range(6, 10)]
+    survivors = [("k", i) for i in range(n_keys - limit, n_keys)]
+    assert list(eng._SWEEP_CACHE) == survivors
+    assert eng.SWEEP_CACHE_STATS == {"hits": 0, "misses": n_keys,
+                                     "evictions": n_keys - limit}
 
-    # a hit refreshes recency: ("k", 6) must now outlive ("k", 7)
-    assert eng._cache_lookup(("k", 6)) == "runner6"
-    assert eng.SWEEP_CACHE_STATS["hits"] == 1
+    # a hit refreshes recency: the oldest survivor now outlives the next
+    oldest = survivors[0]
+    assert eng._cache_lookup(oldest) == f"runner{oldest[1]}"
     eng._cache_store(("k", 99), "runner99")
-    assert ("k", 6) in eng._SWEEP_CACHE
-    assert ("k", 7) not in eng._SWEEP_CACHE
+    assert list(eng._SWEEP_CACHE) == survivors[2:] + [oldest, ("k", 99)]
+    assert eng.SWEEP_CACHE_STATS == {"hits": 1, "misses": n_keys,
+                                     "evictions": n_keys - limit + 1}
+
+
+def test_every_entry_compiles_into_the_one_bounded_cache(
+        monkeypatch, small_system, small_table):
+    """All six entries build their programs through one runner cache under
+    one bound: the static fast path's runner is evicted like any other."""
+    import collections
+    monkeypatch.setattr(eng, "_SWEEP_CACHE", collections.OrderedDict())
+    monkeypatch.setattr(eng, "SWEEP_CACHE_LIMIT", 3)
+    monkeypatch.setattr(eng, "SWEEP_CACHE_STATS",
+                        {"hits": 0, "misses": 0, "evictions": 0})
+    system, table, n = small_system, small_table, 3
+    t1 = n * system.dt
+    scens = [T.Scenario.make("fcfs", "easy"), T.Scenario.make("sjf")]
+    fresh = lambda: eng.init_state(system, table, 0.0, t1)
+    calls = [
+        lambda: eng.simulate(system, table, scens[0], 0.0, t1),
+        lambda: eng.simulate_static(system, table, "sjf", "easy", 0.0, t1),
+        lambda: eng.simulate_sweep(system, table, scens, 0.0, t1),
+        lambda: eng.simulate_sweep_sharded(system, table, scens, 0.0, t1),
+        lambda: eng.simulate_segment(system, table, fresh(), scens[1], n),
+        lambda: eng.simulate_segment_sweep(system, table,
+                                           [fresh(), fresh()], scens, n),
+    ]
+    ran = []
+    for call in calls:
+        jax.block_until_ready(call())
+        ran.append(eng._last_runner())
+    simulate, static, sweep, sharded, segment, segment_sweep = ran
+    # on the one test device the sharded entry runs the sweep's program,
+    # and a segment from a fresh carry runs simulate's
+    assert sharded is sweep and segment is simulate
+    # four programs in one cache of three: the least recently used one,
+    # the static runner, went when the bound was passed
+    assert list(eng._SWEEP_CACHE.values()) == [sweep, simulate,
+                                               segment_sweep]
+    assert static not in eng._SWEEP_CACHE.values()
+    assert eng.SWEEP_CACHE_STATS == {"hits": 2, "misses": 4,
+                                     "evictions": 1}
 
 
 @pytest.mark.timeout(300)

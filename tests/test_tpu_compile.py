@@ -30,7 +30,6 @@ from repro.datasets import loaders
 from repro.kernels.power_topo import ops
 from repro.obs import phases
 from repro.obs import timing
-from repro.parallel import sharding as psh
 from repro.systems.config import get_system
 
 COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
@@ -82,7 +81,7 @@ def test_frontier_scan_compiles_for_one_chip(topo, frontier):
     one = SingleDeviceSharding(topo.devices[0])
     n_steps = int(DAY_S / system.dt)
     traced = dict(timing.RELEASE_STATS)
-    compiled = eng._segment_fn(system, n_steps).lower(
+    compiled = eng._runner(system, n_steps).lower(
         _shapes(table, one), _shapes(st0, one),
         _shapes(T.Scenario.make("fcfs", "easy"), one), None, None).compile()
     # without grid signals the runner releases nodes by their end time
@@ -154,16 +153,16 @@ def test_power_topo_kernel_compiles_at_frontier_width(topo, kernel):
 def test_sharded_sweep_compiles_on_four_chips_without_collectives(
         topo, frontier):
     system, table, st0 = frontier
-    mesh = psh.sweep_mesh(topo.devices)
+    mesh = eng.sweep_mesh(topo.devices)
     assert mesh.devices.size == 4
     rep = NamedSharding(mesh, PartitionSpec())
-    rows = NamedSharding(mesh, psh.scenario_spec())
+    rows = NamedSharding(mesh, eng.scenario_spec())
     pairs = [("fcfs", "none"), ("fcfs", "easy"), ("sjf", "first-fit"),
              ("ljf", "easy"), ("priority", "easy"), ("sjf", "easy"),
              ("fcfs", "first-fit"), ("replay", "none")]
     scens = T.stack_scenarios([T.Scenario.make(p, b) for p, b in pairs])
-    run = eng.sharded_sweep_fn(system, int(DAY_S / system.dt), None, None,
-                               mesh)
+    run = eng._runner(system, int(DAY_S / system.dt), axes=(None, None),
+                      mesh=mesh)
     compiled = run.lower(_shapes(table, rep), _shapes(st0, rep),
                          _shapes(scens, rows), None, None).compile()
     text = compiled.as_text()
