@@ -402,46 +402,38 @@ def external_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
                 sched.hall_placement_plan(system, st, thermal,
                                           is_replay=False)
         else:
+            order_nodes = None
             free_ok0 = st.free_count
             group_pos = jnp.arange(system.cooling.n_groups, dtype=jnp.int32)
 
     def body(i, carry):
-        node_job, node_end, jstate, start, end, free_count, free_ok = carry
-        j = place_ids[i]
-        ok = j >= 0
-        jj = jnp.maximum(j, 0)
-        need = table.nodes[jj]
+        free_count, free_ok, placed = carry
+        need = need_k[i]
         th_ok = (need <= free_ok) if hall_aware else thermal_ok
-        can = ok & (jstate[jj] == T.QUEUED) & (need <= free_count) & th_ok
+        # a repeated id starts at most once: not if placed earlier here
+        fresh = ~jnp.any(placed & (place_ids == place_ids[i]))
+        can = queued[i] & fresh & (need <= free_count) & th_ok
         if hall_aware:
-            sel = rm.firstfree_mask_ordered(node_job, need, order_nodes)
-        else:
-            sel = rm.firstfree_mask(node_job, need)
-        end_j = st.t + table.wall[jj]
-        node_job, node_end = rm.place(node_job, node_end, sel, jj, end_j,
-                                      can)
-        free_count = free_count - jnp.where(can, need, 0)
-        if hall_aware:
+            # this placement takes the free ranks (c, c + need]
+            c = st.free_count - free_count
             free_ok = free_ok - jnp.where(
-                can, jnp.sum((sel & node_ok).astype(jnp.int32)), 0)
+                can, ok_upto[c + need] - ok_upto[c], 0)
         # (inert carry on a flat plant — the global gate never reads it)
-        jstate = jstate.at[jj].set(jnp.where(can, T.RUNNING, jstate[jj]))
-        start = start.at[jj].set(jnp.where(can, st.t, start[jj]))
-        end = end.at[jj].set(jnp.where(can, end_j, end[jj]))
-        return node_job, node_end, jstate, start, end, free_count, free_ok
+        free_count = free_count - jnp.where(can, need, 0)
+        return free_count, free_ok, placed.at[i].set(can)
 
     with jax.named_scope(phases.ADMISSION):
-        carry = (st.node_job, st.node_end, st.jstate, st.start, st.end,
-                 st.free_count, jnp.int32(free_ok0))
-        (node_job, node_end, jstate, start, end, free_count,
-         _) = jax.lax.fori_loop(0, place_ids.shape[0], body, carry)
-        job_group_nodes = rm.record_placements(
-            st.job_group_nodes, st.node_job, group_pos, place_ids, st.jstate,
-            jstate, table.nodes)
-    st = dataclasses.replace(st, jstate=jstate, start=start, end=end,
-                             node_job=node_job, node_end=node_end,
-                             free_count=free_count,
-                             job_group_nodes=job_group_nodes)
+        jj = jnp.maximum(place_ids, 0)
+        queued = (place_ids >= 0) & (st.jstate[jj] == T.QUEUED)
+        need_k = table.nodes[jj]
+        rank = rm.free_ranks(st.node_job, order_nodes)
+        if hall_aware:
+            ok_upto = rm.ok_upto(rank, node_ok)
+        K = place_ids.shape[0]
+        carry = (st.free_count, jnp.int32(free_ok0), jnp.zeros((K,), bool))
+        _, _, placed = jax.lax.fori_loop(0, K, body, carry)
+        st = rm.commit_placements(st, rank, group_pos, place_ids, placed,
+                                  need_k, st.t + table.wall[jj])
     return _tick(system, table, st, grid,
                  None if grid is None else grid.cap_w * cap_scale, wx,
                  setpoint_delta, thermal, cells_offline)
@@ -642,7 +634,7 @@ def _runner(system: SystemConfig, n_steps: int, events=None, *, axes=None,
         rows, rep = scenario_spec(), jax.sharding.PartitionSpec()
         spec = lambda axis: rows if axis == 0 else rep
         # check_vma=False: every loop carry inside engine_step (the scan's
-        # replicated initial state, the admission fori_loop's fresh
+        # replicated initial state, the admission loop's fresh
         # constants) becomes per-device data after one step, which the
         # varying-axes type check rejects. The body has no collective
         # whose result depends on those types: scenario rows never

@@ -6,9 +6,15 @@ Design notes
 * The policy and the backfill mode are **traced integers** (fields of
   ``Scenario``), so an entire sweep of scheduling configurations runs as one
   vmapped program — this is the TPU-native form of the paper's what-if studies.
-* The admission loop is a ``lax.fori_loop`` over the first ``sched_budget``
+* The admission loop is a ``lax.scan`` over the first ``sched_budget``
   entries of the key-sorted queue: bounded work per cycle, like a production
   scheduler's main loop.
+* The loop decides on scalars only: free counts, the power projection, the
+  EASY flags and shadow, over the candidates' inputs gathered once before
+  it. Nothing is freed within a pass, so the jobs it starts take the free
+  nodes one after another; the node map, per-node end times and job
+  lifecycle are written once after the loop, from one rank of the free
+  nodes (``resource_manager.commit_placements``).
 * EASY (Mu'alem & Feitelson): when the queue head cannot start, it receives a
   reservation at the *shadow time* (earliest time enough nodes free up, from
   the running jobs' end times); later jobs may backfill iff they fit now and
@@ -21,8 +27,6 @@ Design notes
   and standard in case (b)).
 """
 from __future__ import annotations
-
-import dataclasses
 
 import numpy as np
 
@@ -321,31 +325,19 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
             cum_nodes = jnp.zeros((1,), jnp.int32)
         else:
             end_sorted, cum_nodes = release_profile(table, st)
-    n_nodes = system.n_nodes
     t = st.t
+    K = min(system.sched_budget, table.num_jobs)
 
-    def body(i, carry):
-        (node_job, node_end, jstate, start, end, free_count, free_ok, proj,
-         blocked_any, head_blocked, head_capped,
+    def body(carry, x):
+        (free_count, free_ok, proj, blocked_any, head_blocked, head_capped,
          shadow_t, shadow_extra) = carry
-        j = order[i]
-        valid = jstate[j] == T.QUEUED
-        # replay eligibility re-gate (queue_order already filtered, but jobs
-        # whose recorded start is still in the future must keep waiting)
-        valid &= jnp.where(is_replay, table.rec_start[j] <= t, True)
-        need = table.nodes[j]
+        valid, need, limit, add_pw = x
 
         # --- does it fit right now? ---
-        # Placement is deterministic first-free (lowest-index free nodes);
-        # the dataset generators use the same rule, so replay reproduces the
-        # recorded occupancy without storing per-node assignments. On a
-        # multi-hall plant the scan order is the hall-preference
-        # permutation instead (coolest hall first, index-stable within a
-        # hall; identity under replay and when every hall is equally cool).
-        if hall_aware:
-            sel = rm.firstfree_mask_ordered(node_job, need, order_nodes)
-        else:
-            sel = rm.firstfree_mask(node_job, need)
+        # Placement is deterministic first-free (lowest-index free nodes,
+        # or the hall-preference order on a multi-hall plant), written
+        # after the loop from the free ranks; the dataset generators use
+        # the same rule, so replay reproduces the recorded occupancy.
         fits = need <= free_count
 
         # --- EASY reservation for the first blocked (head) job ---
@@ -358,7 +350,7 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
         # a cap-blocked head has no node-shadow to reserve (power, not
         # nodes, is scarce): EASY halts instead, so backfill cannot eat
         # the headroom the head is waiting for
-        easy_ok = ((t + table.limit[j] <= shadow_t) |
+        easy_ok = ((t + limit <= shadow_t) |
                    (need <= shadow_extra)) & ~head_capped
         if static:
             can_bf = {T.BF_NONE: ~blocked_any,
@@ -376,13 +368,13 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
             )
         # cap-aware admission: starting this job must not breach the cap
         if has_grid:
-            cap_ok = proj + est_add_pw[j] <= cap_active
+            cap_ok = proj + add_pw <= cap_active
             if dr is not None:
                 # notice-window pre-positioning: a job that would still be
                 # running when the announced DR cap engages must also fit
                 # under *that* cap
-                runs_into = dr.in_notice & (t + table.limit[j] > dr.start_s)
-                cap_ok &= ~runs_into | (proj + est_add_pw[j] <= dr.cap_w)
+                runs_into = dr.in_notice & (t + limit > dr.start_s)
+                cap_ok &= ~runs_into | (proj + add_pw <= dr.cap_w)
         else:
             cap_ok = jnp.bool_(True)
         # thermal admission: flat plant -> all-or-nothing gate; multi-hall
@@ -397,42 +389,43 @@ def schedule_step(system: SystemConfig, table: T.JobTable, st: T.SimState,
         place = valid & fits & jnp.where(is_replay, True,
                                          can_bf & cap_ok & th_ok)
 
-        # --- commit ---
-        end_j = t + table.wall[j]
-        node_job, node_end = rm.place(node_job, node_end, sel, j, end_j,
-                                      place)
-        free_count = free_count - jnp.where(place, need, 0)
+        # --- commit (scalars; the placement is written after the loop) ---
         if hall_aware:
+            # this placement takes the free ranks (c, c + need]
+            c = st.free_count - free_count
             free_ok = free_ok - jnp.where(
-                place, jnp.sum((sel & node_ok).astype(jnp.int32)), 0)
+                place, ok_upto[c + need] - ok_upto[c], 0)
         # (on a flat plant free_ok is inert carry: the all-or-nothing gate
         # never reads it)
+        free_count = free_count - jnp.where(place, need, 0)
         if has_grid:
-            proj = proj + jnp.where(place, est_add_pw[j], 0.0)
-        jstate = jstate.at[j].set(jnp.where(place, T.RUNNING, jstate[j]))
-        start = start.at[j].set(jnp.where(place, t, start[j]))
-        end = end.at[j].set(jnp.where(place, end_j, end[j]))
+            proj = proj + jnp.where(place, add_pw, 0.0)
 
         blocked_any |= valid & (~fits | ~cap_ok | ~th_ok)
         head_blocked |= valid & ~fits
         head_capped |= valid & fits & (~cap_ok | ~th_ok)
-        return (node_job, node_end, jstate, start, end, free_count, free_ok,
-                proj, blocked_any, head_blocked, head_capped,
-                shadow_t, shadow_extra)
+        return (free_count, free_ok, proj, blocked_any, head_blocked,
+                head_capped, shadow_t, shadow_extra), place
 
     with jax.named_scope(phases.ADMISSION):
-        carry = (st.node_job, st.node_end, st.jstate, st.start, st.end,
-                 st.free_count, jnp.int32(free_ok0),
-                 jnp.float32(proj_pw), jnp.bool_(False), jnp.bool_(False),
-                 jnp.bool_(False), jnp.float32(jnp.inf), jnp.int32(0))
-        K = min(system.sched_budget, table.num_jobs)
-        (node_job, node_end, jstate, start, end, free_count,
-         *_rest) = jax.lax.fori_loop(0, K, body, carry)
-        job_group_nodes = rm.record_placements(
-            st.job_group_nodes, st.node_job, group_pos, order[:K], st.jstate,
-            jstate, table.nodes)
-
-    return dataclasses.replace(st, jstate=jstate, start=start, end=end,
-                               node_job=node_job, node_end=node_end,
-                               free_count=free_count,
-                               job_group_nodes=job_group_nodes)
+        # the candidates' inputs, gathered once: ``order`` is a
+        # permutation, so no iteration changes another candidate's state
+        cand = order[:K]
+        valid = st.jstate[cand] == T.QUEUED
+        # replay eligibility re-gate (queue_order already filtered, but jobs
+        # whose recorded start is still in the future must keep waiting)
+        valid &= jnp.where(is_replay, table.rec_start[cand] <= t, True)
+        need = table.nodes[cand]
+        add_pw = est_add_pw[cand] if has_grid else None
+        rank = rm.free_ranks(st.node_job, order_nodes)
+        if hall_aware:
+            ok_upto = rm.ok_upto(rank, node_ok)
+        carry = (st.free_count, jnp.int32(free_ok0), jnp.float32(proj_pw),
+                 jnp.bool_(False), jnp.bool_(False), jnp.bool_(False),
+                 jnp.float32(jnp.inf), jnp.int32(0))
+        # two iterations a trip: the body is a chain of scalar ops, and
+        # the loop's own control is a large part of its time on the chip
+        _, placed = jax.lax.scan(
+            body, carry, (valid, need, table.limit[cand], add_pw), unroll=2)
+        return rm.commit_placements(st, rank, group_pos, cand, placed, need,
+                                    t + table.wall[cand])

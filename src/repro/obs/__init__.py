@@ -6,8 +6,8 @@
   (file or socket, PR 5 transport framing);
 * ``obs.timing``   — program spans, always recorded in memory and on the
   profiler's clock (and on an installed ``SpanTimer`` for the manifest),
-  compile counters, the engine's trace-time release counter, and the
-  bridge's latency histogram;
+  compile counters, the engine's trace-time release and placement
+  counters, and the bridge's latency histogram;
 * ``obs.phases``   — the engine step's phase names and the device time
   each one takes in a compiled runner;
 * ``obs.reporter`` — logging-based CLI output (progress → stderr,

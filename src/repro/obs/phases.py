@@ -30,7 +30,7 @@ PREPARE = "engine.prepare"        # completions, node release, account fold,
 FAILURES = "engine.failures"      # failure/repair draws, demand response
 QUEUE_ORDER = "engine.queue_order"  # thermal signal, policy keys, lexsort,
 #                                   release-profile argsort, hall plan
-ADMISSION = "engine.admission"    # the admission fori_loop and placement
+ADMISSION = "engine.admission"    # the admission loop and placement
 POWER = "engine.power"            # job and node power, the cap-aware
 #                                   baseline, DVFS cap, conversion losses
 COOLING = "engine.cooling"        # node -> CDU -> hall sums and the plant

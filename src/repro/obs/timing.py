@@ -212,6 +212,10 @@ COMPILE_STATS = {"compiles": 0, "compile_s": 0.0, "lower_s": 0.0}
 # path's gather of the per-job flag. Counted at trace time, so each
 # compiled runner adds one; nothing runs on the device.
 RELEASE_STATS = {"node_end": 0, "job_gather": 0}
+# Traces of the admission loop's placement, by the order its free ranks
+# are taken in (``resource_manager.free_ranks``): node-index order, or a
+# hall preference order. Counted at trace time, like RELEASE_STATS.
+PLACEMENT_STATS = {"ranks": 0, "ranks_ordered": 0}
 _stats_lock = threading.Lock()
 
 

@@ -113,6 +113,23 @@ def _tick_pairs(system, table, st, grid):
     }
 
 
+def firstfree_mask(node_job, need):
+    """The first ``need`` free nodes in node order (a -2 down node is not
+    free): the selection one placement made before placements were
+    written from free ranks after the loop."""
+    free = node_job == -1
+    return free & (jnp.cumsum(free.astype(jnp.int32)) <= need)
+
+
+def firstfree_mask_ordered(node_job, need, order):
+    """``firstfree_mask`` taken in the preference order ``order`` (i32[N]
+    permutation of node indices; identity reproduces it exactly)."""
+    free = node_job == -1
+    free_o = free[order]
+    sel_o = free_o & (jnp.cumsum(free_o.astype(jnp.int32)) <= need)
+    return jnp.zeros_like(free).at[order].set(sel_o)
+
+
 def _external_ids(table, st, k=8):
     """The first ``k`` queued jobs by submit time, padded with -1: a
     stand-in for an external scheduler's decisions."""
@@ -200,7 +217,7 @@ def test_placement_counts_match_successive_selections(n_nodes, n_groups,
         free = int((node_job == -1).sum())
         if ask == 0 or ask > free:
             continue                      # not placed: this slot is empty
-        sel = np.asarray(rm.firstfree_mask_ordered(
+        sel = np.asarray(firstfree_mask_ordered(
             jnp.asarray(node_job), jnp.int32(ask), jnp.asarray(order)))
         want[k] = np.bincount(gid[sel], minlength=n_groups)
         need[k] = ask

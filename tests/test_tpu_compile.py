@@ -91,13 +91,17 @@ def test_frontier_scan_compiles_for_one_chip(topo, frontier):
     assert mem.temp_size_in_bytes < 16 * 2**30
     assert n_steps == 5760
     # the chip's compiler keeps the phase scopes, so a trace's device time
-    # reads per phase; the node cumsum it rewrites without a name stays
-    # with the admission loop that runs it
+    # reads per phase
     ins = phases.instructions(compiled.as_text())
     assert {i.phase for i in ins if i.depth > 0} >= set(phases.PHASES) - {
         phases.FAILURES}
-    assert {i.phase for i in ins if i.opcode == "reduce-window"
-            and i.depth > 1} == {phases.ADMISSION}
+    # the admission loop decides on scalars: no cumsum (a ``reduce-window``
+    # once the compiler rewrites it) runs inside it, and the free-rank
+    # cumsum over the nodes runs once a step, in the scan's body
+    windows = [i for i in ins if i.opcode == "reduce-window"]
+    assert windows and all(i.depth == 1 for i in windows)
+    assert [i.depth for i in windows if _elements(
+        compiled.as_text(), i.name) == system.n_nodes] == [1]
     # power and heat per CDU come from the per-group occupancy: no gather
     # spreads a per-job value over the nodes there
     assert not node_wide_gathers(compiled.as_text(), system.n_nodes,
@@ -106,6 +110,12 @@ def test_frontier_scan_compiles_for_one_chip(topo, frontier):
     # flag (pred) or value is gathered onto the nodes there either
     assert not node_wide_gathers(compiled.as_text(), system.n_nodes,
                                  {phases.PREPARE})
+
+
+def _elements(text, name):
+    """Elements of instruction ``name``'s (array) output in HLO ``text``."""
+    m = re.search(r"%" + re.escape(name) + r" = [a-z]\w*\[([\d,]*)\]", text)
+    return int(np.prod([int(d) for d in m.group(1).split(",") if d]))
 
 
 _GATHER = re.compile(r"=\s*[a-z]\w*\[([\d,]*)\]\S*\s+gather\(")
